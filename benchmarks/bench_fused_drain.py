@@ -347,16 +347,17 @@ def _roofline_phase(out: Dict, quick: bool = False,
 
         feats = jax.device_put(se.make_features(bat),
                                se.feature_sharding(se.make_features(bat)))
-        compiled = jax.jit(se.evaluate).lower(feats).compile()
+        params = se.evaluate.params
+        compiled = jax.jit(se.evaluate.apply).lower(params, feats).compile()
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):      # older jax returns [dict]
             ca = ca[0] if ca else {}
         flops_b = float((ca or {}).get("flops", 0.0))
         bytes_b = float((ca or {}).get("bytes accessed", 0.0))
-        jax.block_until_ready(compiled(feats))   # warm the AOT exec
+        jax.block_until_ready(compiled(params, feats))  # warm the AOT exec
         t0 = time.perf_counter()
         for _ in range(3):
-            jax.block_until_ready(compiled(feats))
+            jax.block_until_ready(compiled(params, feats))
         eval_s = (time.perf_counter() - t0) / 3
 
         off = ai * 1_000_000_000
@@ -426,14 +427,10 @@ def main(n_requests: int = 768, items_per_req: int = 64,
     _parity_phase(out)
     _roofline_phase(out, quick=quick, archs=roofline_archs,
                     full=roofline_full)
-    # The ways-leading Trust-DB retile's honest VMEM claim at the
-    # production config (legacy slots-leading padded 4 ways -> 128
-    # lanes: 32 MiB, unlowerable; ways-leading pads 4 -> 8 sublanes).
+    # The shed_partition kernel's VMEM claim at the production config
+    # (4 ways; the Trust DB itself stays in HBM behind an XLA gather).
     from repro.kernels.shed_partition import shed_partition_vmem_bytes
-    out["shed_partition_vmem_bytes"] = shed_partition_vmem_bytes(
-        65536, 4)
-    out["shed_partition_vmem_bytes_legacy"] = shed_partition_vmem_bytes(
-        65536, 4, ways_leading=False)
+    out["shed_partition_vmem_bytes"] = shed_partition_vmem_bytes(4)
 
     print(f"workload: {n_requests} requests x {items_per_req} items "
           f"(batch bound {batch_items}, serving-loop driver)")

@@ -31,6 +31,8 @@ def _gates(name, rows):
 
 
 def main(quick: bool = False) -> int:
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_adaptive, bench_capacity,
                             bench_cluster, bench_elastic, bench_fanout,
                             bench_fleet, bench_fused_drain,

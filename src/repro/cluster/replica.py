@@ -40,6 +40,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -342,7 +343,10 @@ class ReplicaHandle:
         buckets = np.zeros(n, np.int32)
         feats = {k: np.zeros((n,) + tuple(shape), dtype=dtype)
                  for k, (shape, dtype) in feature_schema.items()}
-        cache_snap, prior_snap = sh.cache, sh.prior
+        # Copies, not references: the fused step donates the cache and
+        # prior it is handed, so the live arrays die in sh.process.
+        cache_snap = jax.tree.map(jnp.copy, sh.cache)
+        prior_snap = jax.tree.map(jnp.copy, sh.prior)
         deltas_snap, self._cache_deltas = self._cache_deltas, []
         t_snap = self.clock.t if self.clock is not None else None
         try:

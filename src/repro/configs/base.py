@@ -106,7 +106,6 @@ class TransformerConfig:
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     remat: bool = True                 # activation checkpointing per block
-    use_pallas: bool = False           # flash kernels (TPU target; CPU uses ref)
     # scan over layers: keeps HLO size O(1) in depth — required for the
     # 48-layer full configs to compile quickly in the dry-run.
     scan_layers: bool = True
@@ -260,12 +259,9 @@ class TrustIRConfig:
     cache_ways: int = 4
     # Cache array layout: True (default) stores keys/values/age as
     # (n_ways, n_slots) — each way one contiguous slot-indexed row, so
-    # the shed_partition kernel's unrolled multi-way probe is one
-    # strided row load per lane block and the VMEM-resident arrays pad
-    # the ways axis to the 8-sublane tile (4 MiB at the production
-    # config) instead of the slot axis to 128 lanes (32 MiB — the
-    # legacy (n_slots, n_ways) layout, kept for parity testing and old
-    # snapshots; every cache op infers the layout from the shape).
+    # the probe's XLA gather reads one row per way. False is the legacy
+    # (n_slots, n_ways) layout, kept for parity testing and old
+    # snapshots; every cache op infers the layout from the shape.
     cache_ways_leading: bool = True
     # Average-trust prior
     prior_buckets: int = 1              # 1 = paper-faithful global average

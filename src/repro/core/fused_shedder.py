@@ -7,14 +7,21 @@ chunk. The serving hot path doesn't need a wall-clock deadline check
 ``shed_plan`` math), so ``FusedLoadShedder`` collapses the whole
 shedding decision into ONE device dispatch per micro-batch:
 
-    shed_partition (Pallas: VMEM-resident Trust-DB probe + tier scan,
-                    SMEM write-cursor emits compacted eval ranks)
+    shed_partition (XLA gather of each key's Trust-DB set, then
+                    Pallas: way compare + tier scan, SMEM
+                    write-cursor emits compacted eval ranks)
       -> eval_indices_from_rank   O(N) scatter, no argsort
       -> static-shape gather      features picked once, on device
       -> evaluator forward        one batched call, no chunk loop
       -> scatter + combine        trust per tier
       -> TC.insert / AT.update    cache + prior fold-back, donated
-                                  buffers update in place on TPU/GPU
+                                  buffers update in place
+
+The evaluator's parameters enter the step as an argument (an
+``Evaluator``'s ``params``), never as constants folded into the
+compiled step. The cache and prior are donated: after a dispatch only
+the step's outputs (``self.cache``/``self.prior``) are live, so code
+that keeps a state across a dispatch must copy it first.
 
 Features transfer to device once per *batch* (the host path converts
 the pytree then re-gathers per chunk), and the transfer is its own
@@ -40,12 +47,14 @@ paths agree exactly.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import TrustIRConfig
 from repro.core import average_trust as AT
@@ -56,6 +65,16 @@ from repro.core.regimes import classify
 from repro.core.shedder import (LoadShedder, ShedResult, SimClock,
                                 TIER_CACHED, TIER_EVAL, TIER_PRIOR,
                                 combine_trust, eval_indices_from_rank)
+
+
+def _mesh_of(params) -> Optional[Mesh]:
+    """The multi-device mesh a sharded evaluator's parameters live on,
+    or None when they sit on one device."""
+    for leaf in jax.tree.leaves(params):
+        sharding = getattr(leaf, "sharding", None)
+        if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+            return sharding.mesh
+    return None
 
 
 class StagedBatch:
@@ -138,7 +157,9 @@ class PendingShed:
 class FusedLoadShedder(LoadShedder):
     """Drop-in ``LoadShedder`` whose ``process`` runs the fused device
     step. ``evaluate_batch`` must be jax-traceable: features pytree
-    (leading dim ``max_evals``) -> (max_evals,) scores. The host
+    (leading dim ``max_evals``) -> (max_evals,) scores. When it carries
+    ``apply``/``params`` (a ``serving.evaluators.Evaluator``) the step
+    calls ``apply`` with the params as a step argument. The host
     executor's ``evaluate_chunk`` protocol is satisfied by the same
     callable whenever it is traceable (every ``serving.evaluators``
     backend is), so baseline drivers can still call the inherited
@@ -154,8 +175,6 @@ class FusedLoadShedder(LoadShedder):
                  sim_clock: Optional[SimClock] = None,
                  adaptive=None,
                  max_evals: Optional[int] = None,
-                 interpret: Optional[bool] = None,
-                 donate: Optional[bool] = None,
                  feature_sharding=None):
         """``feature_sharding`` (optional) places staged features for a
         mesh-sharded evaluator: a pytree of ``jax.sharding.Sharding``
@@ -175,33 +194,51 @@ class FusedLoadShedder(LoadShedder):
         self.evaluate_batch = evaluate_batch
         self.max_evals = max_evals
         self.feature_sharding = feature_sharding
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else interpret)
-        # Buffer donation is a no-op (with a warning) on CPU; only ask
-        # for in-place cache/prior updates where XLA implements it.
-        if donate is None:
-            donate = jax.default_backend() in ("tpu", "gpu")
-        self._step = jax.jit(
-            self._step_impl, static_argnames=("max_evals",),
-            donate_argnums=(0, 1) if donate else ())
+        apply = getattr(evaluate_batch, "apply", None)
+        if apply is None:
+            self._apply = lambda _params, feats: evaluate_batch(feats)
+            self._eval_params = None
+        else:
+            self._apply = apply
+            self._eval_params = evaluate_batch.params
+        self._mesh = _mesh_of(self._eval_params)
+        if self._mesh is not None:
+            # Start the state where the step leaves it (replicated on
+            # the evaluator's mesh), so the second batch does not
+            # compile the step again for a new input placement.
+            rep = NamedSharding(self._mesh, P())
+            self.cache = jax.device_put(self.cache, rep)
+            self.prior = jax.device_put(self.prior, rep)
+        self._step = jax.jit(self._step_impl,
+                             static_argnames=("max_evals",),
+                             donate_argnums=(0, 1))
         # Wall time of the last throughput observation: pipelined
         # batches overlap, so each observation charges only the
         # marginal window since the previous one (see _finish).
         self._last_obs_wall = 0.0
 
     # -- the fused device step ----------------------------------------------
-    def _step_impl(self, cache, prior, keys, buckets, valid, features,
-                   u_capacity, u_threshold, budget_total, *,
+    def _step_impl(self, cache, prior, eval_params, keys, buckets, valid,
+                   features, u_capacity, u_threshold, budget_total, *,
                    max_evals: int):
+        from repro.distribution.constraints import shard_map
+        from repro.kernels.ops import interpret_mode
         from repro.kernels.shed_partition import shed_partition
         n = keys.shape[0]
         # (8, 128) lane-shaped blocks — the native f32/i32 TPU tile;
         # the kernel pads ragged tails internally, so any batch budget
         # (chunk-aligned or not) takes the same code path.
-        tier, cval, rank = shed_partition(
-            keys, valid, cache["keys"], cache["values"],
-            u_capacity, u_threshold, budget_total,
-            budget_is_total=True, interpret=self.interpret)
+        probe = functools.partial(shed_partition, budget_is_total=True,
+                                  interpret=interpret_mode())
+        if self._mesh is not None:
+            # XLA cannot partition a Mosaic kernel: on a multi-device
+            # evaluator mesh every device runs the probe over the
+            # whole (small, replicated) batch.
+            probe = shard_map(probe, mesh=self._mesh, in_specs=P(),
+                              out_specs=P())
+        tier, cval, rank = probe(keys, valid, cache["keys"],
+                                 cache["values"], u_capacity, u_threshold,
+                                 budget_total)
         # Safety on a too-small max_evals: overflow evals fall back to
         # the prior tier (no-drop) instead of silently scoring 0. The
         # default max_evals = batch capacity can never overflow.
@@ -210,7 +247,7 @@ class FusedLoadShedder(LoadShedder):
         idx, eval_valid = eval_indices_from_rank(rank, max_evals)
         gidx = jnp.minimum(idx, n - 1)              # clamp pad slots
         sub = jax.tree.map(lambda a: a[gidx], features)
-        scores = self.evaluate_batch(sub)           # (max_evals,)
+        scores = self._apply(eval_params, sub)      # (max_evals,)
         scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
             jnp.where(eval_valid, scores.astype(jnp.float32), 0.0),
             mode="drop")
@@ -277,9 +314,9 @@ class FusedLoadShedder(LoadShedder):
             WarmupGate.signature(n_total, staged.feats_j)
             + (max_evals,))
         trust, tier, n_evald, self.cache, self.prior = self._step(
-            self.cache, self.prior, staged.keys_j, staged.buckets_j,
-            staged.valid_j, staged.feats_j, ucap, uthr, budget_total,
-            max_evals=max_evals)
+            self.cache, self.prior, self._eval_params, staged.keys_j,
+            staged.buckets_j, staged.valid_j, staged.feats_j, ucap, uthr,
+            budget_total, max_evals=max_evals)
         pending = PendingShed(self, trust, tier, n_evald,
                               t_start=staged.t_start,
                               wall_start=staged.wall_start,

@@ -7,15 +7,13 @@ vectorized hashing inside the step function (DESIGN.md §2). Eviction is
 oldest-age within the set (LRU over ways). Key 0 is reserved for "empty".
 
 Layout: the default is **(n_ways, n_slots) — ways-leading** — so each
-way is one contiguous slot-indexed row. The ``shed_partition`` kernel's
-unrolled per-way probe then gathers from a single strided row per way
-(ways pad to the 8-sublane float32 tile instead of the slot axis padding
-to 128 lanes, which made the legacy layout unlowerable at the production
-cache config). The legacy ``(n_slots, n_ways)`` slots-leading layout is
-still accepted everywhere: every op infers the layout from the array
-shape (the ways axis is the strictly smaller one — ``init`` enforces
-``n_ways < n_slots``), so snapshots and handoffs from either layout
-interoperate. Under jit, shapes are static, so the inference is a
+way is one contiguous slot-indexed row, and :func:`candidates` (the
+probe's gather, shared by :func:`lookup` and the ``shed_partition``
+kernel wrapper) reads one row per way. The legacy ``(n_slots,
+n_ways)`` slots-leading layout is still accepted everywhere: every op
+infers the layout from the array shape (the ways axis is the strictly
+smaller one — ``init`` enforces ``n_ways < n_slots``), so snapshots
+and handoffs from either layout interoperate. Under jit, shapes are static, so the inference is a
 Python-time branch with zero traced cost.
 
 Purely functional: every op returns a new state pytree, so the cache
@@ -66,23 +64,27 @@ def init(n_slots: int, n_ways: int, *,
     }
 
 
+def candidates(cache_keys: jnp.ndarray, cache_values: jnp.ndarray,
+               keys: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The set each key hashes to, one row per way: ``(cand_keys,
+    cand_values)``, each (n_ways, N). This XLA gather is the probe's
+    only data-dependent read; the ``shed_partition`` kernel consumes
+    its output as lane-shaped blocks."""
+    n_slots, _, ways_leading = dims(cache_keys.shape)
+    slot = (_hash32(keys) % jnp.uint32(n_slots)).astype(jnp.int32)
+    if ways_leading:
+        return cache_keys[:, slot], cache_values[:, slot]
+    return cache_keys[slot].T, cache_values[slot].T
+
+
 def lookup(state: Dict, keys: jnp.ndarray
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """keys: (N,) uint32 (nonzero) -> (values (N,) f32, hit (N,) bool)."""
-    n_slots, _, ways_leading = dims(state["keys"].shape)
-    slot = (_hash32(keys) % jnp.uint32(n_slots)).astype(jnp.int32)
-    if ways_leading:
-        cand_k = state["keys"][:, slot]              # (ways, N)
-        match = cand_k == keys[None, :].astype(jnp.uint32)
-        hit = jnp.any(match, axis=0) & (keys != 0)
-        way = jnp.argmax(match, axis=0)              # first matching way
-        vals = state["values"][way, slot]
-    else:
-        cand_k = state["keys"][slot]                 # (N, ways)
-        match = cand_k == keys[:, None].astype(jnp.uint32)
-        hit = jnp.any(match, axis=-1) & (keys != 0)
-        way = jnp.argmax(match, axis=-1)             # first matching way
-        vals = state["values"][slot, way]
+    cand_k, cand_v = candidates(state["keys"], state["values"], keys)
+    match = cand_k == keys[None, :].astype(jnp.uint32)
+    hit = jnp.any(match, axis=0) & (keys != 0)
+    way = jnp.argmax(match, axis=0)                  # first matching way
+    vals = jnp.take_along_axis(cand_v, way[None, :], axis=0)[0]
     return jnp.where(hit, vals, 0.0), hit
 
 

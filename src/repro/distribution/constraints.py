@@ -14,37 +14,15 @@ from jax.sharding import PartitionSpec as P
 
 
 def ambient_mesh():
-    try:                                  # jax >= 0.5: jax.set_mesh
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not getattr(m, "empty", True):
-            return m
-    except Exception:
-        pass
-    try:                                  # jax 0.4.x: `with mesh:` context
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m is not None and not getattr(m, "empty", True):
-            return m
-    except Exception:
-        pass
-    return None
-
-
-def use_mesh(mesh):
-    """Version-portable ambient-mesh context: ``jax.set_mesh`` on new
-    jax, the classic ``with mesh:`` resource context on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """The mesh set by ``jax.set_mesh``, or None when there is none."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map (replication checks off on both)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checks off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_in_mesh(name: str) -> bool:

@@ -7,25 +7,23 @@ deadline budget, (4) everything else falls to the average-trust prior.
 
 Kernel structure: the (N,) arrival stream is laid out row-major as
 (rows, 128) and the grid walks **(block_rows, 128) lane-shaped blocks**
-— the native float32/int32 TPU tile is (8, 128), so the default block
-is exactly VPU-shaped instead of the 1-D blocks the kernel ran before
-(fine in interpret mode, but a production lowering wants registers
-full). Arrival order is row-major within a block; the running scans are
-two-pass 2-D cumsums (cumsum along lanes, then a sublane offset of row
-totals) — vector ops only, no 1-D reshapes. The cache (keys/values,
-set-associative) is VMEM-resident across all grid steps. Its layout is
-inferred from the array shape (``trust_cache.dims``): the default
-**(n_ways, n_slots) ways-leading** retile makes each way one contiguous
-slot-indexed row, so the unrolled per-way probe is ONE strided row load
-per lane block (``ck_ref[w, slot]``) and the resident arrays pad the
-ways axis to the 8-sublane tile — 4 MiB at the production config
-(65536 slots x 4→8 ways x 8 B), comfortably inside the ~16 MiB VMEM
-budget. The legacy (n_slots, n_ways) layout still runs (per-way
-element gather), but its lane-axis padding (ways 4 → 128 lanes) makes
-the resident claim 32 MiB at the production config — the retile is
-what lets the production cache actually lower.
-:func:`shed_partition_vmem_bytes` computes the measured, padding-honest
-budget handed to the compiler as ``vmem_limit_bytes``.
+— the native float32/int32 TPU tile is (8, 128). Arrival order is
+row-major within a block; the running scans are two-pass 2-D cumsums
+(cumsum along lanes, then a sublane offset of row totals) — vector ops
+only, no 1-D reshapes.
+
+The Trust-DB probe is split at the one data-dependent read. The
+wrapper gathers each key's set with XLA (``trust_cache.candidates``:
+hash -> slot -> one (n_ways, N) row per way, from either cache layout)
+and hands the kernel those candidates as (n_ways, block_rows, 128)
+blocks; the kernel compares them against the keys way by way (first
+matching way wins, as in ``trust_cache.lookup``). Mosaic has no
+vector gather from a VMEM ref, so a kernel that indexes the cache by
+slot does not lower; with the gather outside, the cache stays in HBM,
+its size never enters the kernel's VMEM claim, and
+:func:`shed_partition_vmem_bytes` counts only the double-buffered
+blocks, handed to the compiler as ``vmem_limit_bytes``.
+
 Running counters (valid-so-far, drop-queue-evals-so-far, normal-queue
 evals, EVAL-tier items) live in SMEM scratch and carry across the
 sequential grid, making the tier assignment an exact scan without host
@@ -71,54 +69,53 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.shedder import (TIER_CACHED, TIER_EVAL, TIER_INVALID,
                                 TIER_PRIOR)
-from repro.core.trust_cache import dims as cache_dims
+from repro.core import trust_cache as TC
 
 LANES = 128          # last-dim tile width (every dtype)
 SUBLANES = 8         # float32/int32 sublane tile height
 
 
-def _hash32(x):
-    x = x.astype(jnp.uint32)
-    x = (x ^ (x >> 16)) * jnp.uint32(0x7FEB352D)
-    x = (x ^ (x >> 15)) * jnp.uint32(0x846CA68B)
-    return x ^ (x >> 16)
-
-
 def _cumsum_rowmajor(x: jnp.ndarray) -> jnp.ndarray:
     """Inclusive cumulative sum in row-major (arrival) order over a
-    (rows, LANES) block, built from 2-D vector ops only: a lane-axis
-    cumsum plus the exclusive running total of preceding rows."""
-    lane = jnp.cumsum(x, axis=1)
-    row_tot = lane[:, -1:]                           # (rows, 1)
-    row_off = jnp.cumsum(row_tot, axis=0) - row_tot  # exclusive
-    return lane + row_off
+    (rows, LANES) block of 0/1 int32 flags.
+
+    Mosaic has no cumsum, so the scan is two matmuls against 0/1
+    triangles: ``x @ U`` is the lane-axis prefix sum, and ``Ls @ (x @
+    J)`` adds the totals of all preceding rows (``J`` all ones, ``Ls``
+    strictly lower-triangular). Every operand is a small integer
+    (flags, row totals <= 128), exact in bf16, and the f32 accumulator
+    is exact far past any block's count — the result is the integer
+    scan, not an approximation of it."""
+    rows = x.shape[0]
+    xf = x.astype(jnp.float32)
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    upper = (r <= c).astype(jnp.float32)             # U[i, j] = i <= j
+    ones = jnp.ones((LANES, LANES), jnp.float32)
+    rr = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+    rc = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+    lower = (rc < rr).astype(jnp.float32)            # Ls[i, j] = j < i
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    lane = dot(xf, upper)
+    row_off = dot(lower, dot(xf, ones))
+    return (lane + row_off).astype(jnp.int32)
 
 
-def shed_partition_vmem_bytes(n_slots: int, n_ways: int,
-                              block_rows: int = SUBLANES, *,
-                              ways_leading: bool = True) -> int:
-    """Measured VMEM budget of one grid step: the resident Trust-DB
-    (keys + values, tile-padding honest) plus the double-buffered
-    in/out blocks (keys, valid; tier, cval, rank — all 4-byte lanes)
-    and scratch slack.
-
-    Ways-leading (n_ways, n_slots) arrays pad ways up to the 8-sublane
-    float32 tile (4 MiB at 65536 x 4); the legacy slots-leading layout
-    pads ways up to 128 lanes instead — 32 MiB at the production
-    config, which is why the retile exists."""
-    if ways_leading:
-        cache = 2 * max(n_ways, SUBLANES) * n_slots * 4
-    else:
-        cache = 2 * n_slots * max(n_ways, LANES) * 4
-    blocks = 5 * block_rows * LANES * 4
-    return cache + 2 * blocks + (128 << 10)          # 128 KiB slack
+def shed_partition_vmem_bytes(n_ways: int,
+                              block_rows: int = SUBLANES) -> int:
+    """VMEM claim of one grid step: the double-buffered in/out blocks
+    (keys, valid, n_ways candidate keys and values; tier, cval, rank —
+    all 4-byte lanes) plus scratch slack. The Trust DB itself stays in
+    HBM, so its size does not appear."""
+    blocks = (5 + 2 * n_ways) * block_rows * LANES * 4
+    return 2 * blocks + (128 << 10)          # 128 KiB slack
 
 
 def _shed_kernel(params_ref,              # SMEM: [ucap, uthr, budget]
                  keys_ref, valid_ref, ck_ref, cv_ref,
                  tier_ref, cval_ref, rank_ref,
-                 cnt_scr, *, block_rows: int, n_slots: int, n_ways: int,
-                 ways_leading: bool, budget_is_total: bool):
+                 cnt_scr, *, block_rows: int, n_ways: int,
+                 budget_is_total: bool):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -134,19 +131,12 @@ def _shed_kernel(params_ref,              # SMEM: [ucap, uthr, budget]
     keys = keys_ref[...]                           # (block_rows, 128)
     valid = valid_ref[...] != 0
 
-    # --- Trust DB probe (set-associative, VMEM-resident) ---
-    slot = (_hash32(keys) % jnp.uint32(n_slots)).astype(jnp.int32)
+    # --- Trust DB probe: compare each way's gathered candidates ---
     hit = jnp.zeros((block_rows, LANES), jnp.bool_)
     val = jnp.zeros((block_rows, LANES), jnp.float32)
     for w in range(n_ways):                        # ways unrolled
-        if ways_leading:
-            # One strided load per lane block: way w is a contiguous
-            # slot-indexed row, gathered in place.
-            ck = ck_ref[w, slot]
-            cv = cv_ref[w, slot]
-        else:                                      # legacy layout
-            ck = ck_ref[slot, w]                   # per-way VMEM gather
-            cv = cv_ref[slot, w]
+        ck = ck_ref[w]                             # (block_rows, 128)
+        cv = cv_ref[w]
         m = (ck == keys) & (keys != jnp.uint32(0))
         val = jnp.where(m & ~hit, cv, val)
         hit = hit | m
@@ -205,7 +195,8 @@ def shed_partition(keys: jnp.ndarray, valid: jnp.ndarray,
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """keys: (N,) uint32; valid: (N,) bool; cache_*: (ways, slots) in
     the default ways-leading layout, or legacy (slots, ways) — the
-    layout is inferred from the shape (``trust_cache.dims``).
+    layout is inferred from the shape (``trust_cache.dims``) by the
+    XLA probe gather, never by the kernel.
 
     Returns (tier (N,) int32, cached_vals (N,) f32, eval_rank (N,)
     int32). ``eval_rank`` is the arrival-ordered compacted position of
@@ -238,38 +229,32 @@ def shed_partition(keys: jnp.ndarray, valid: jnp.ndarray,
     rows = (n + n_pad) // LANES
     keys2 = keys_p.reshape(rows, LANES)
     valid2 = valid_p.reshape(rows, LANES)
-    n_slots, n_ways, ways_leading = cache_dims(cache_keys.shape)
-    cache_block = ((n_ways, n_slots) if ways_leading
-                   else (n_slots, n_ways))
+    cand_k, cand_v = TC.candidates(cache_keys, cache_values, keys_p)
+    n_ways = cand_k.shape[0]
+    cand_k = cand_k.reshape(n_ways, rows, LANES)
+    cand_v = cand_v.astype(jnp.float32).reshape(n_ways, rows, LANES)
     params = jnp.asarray([u_capacity, u_threshold, budget_dq], jnp.int32)
 
     kernel = functools.partial(_shed_kernel, block_rows=block_rows,
-                               n_slots=n_slots, n_ways=n_ways,
-                               ways_leading=ways_leading,
+                               n_ways=n_ways,
                                budget_is_total=budget_is_total)
     kwargs = {}
     if not interpret:
-        # Hand the compiler the measured residency claim: cache +
-        # double-buffered blocks must fit, nothing more is needed.
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            vmem_limit_bytes=shed_partition_vmem_bytes(
-                n_slots, n_ways, block_rows, ways_leading=ways_leading))
+        # Hand the compiler the kernel's own claim: double-buffered
+        # blocks, nothing more is needed.
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=shed_partition_vmem_bytes(n_ways,
+                                                       block_rows))
+    block = pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0))
+    ways_block = pl.BlockSpec((n_ways, block_rows, LANES),
+                              lambda i, *_: (0, i, 0))
     tier, cval, rank = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(rows // block_rows,),
-            in_specs=[
-                pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0)),
-                pl.BlockSpec(cache_block, lambda i, *_: (0, 0)),
-                pl.BlockSpec(cache_block, lambda i, *_: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0)),
-            ],
+            in_specs=[block, block, ways_block, ways_block],
+            out_specs=[block, block, block],
             scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
         ),
         out_shape=[
@@ -279,6 +264,6 @@ def shed_partition(keys: jnp.ndarray, valid: jnp.ndarray,
         ],
         interpret=interpret,
         **kwargs,
-    )(params, keys2, valid2, cache_keys, cache_values)
+    )(params, keys2, valid2, cand_k, cand_v)
     return (tier.reshape(-1)[:n], cval.reshape(-1)[:n],
             rank.reshape(-1)[:n])

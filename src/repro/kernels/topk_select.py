@@ -15,7 +15,8 @@ top-k can take more than k items from one block) with a
 selected, ties broken by the minimum flat index, and the winner is
 recorded into a (cand_rows, 128) candidate block via a row-major
 position mask — vector ops only, no 1-D reshapes, no dynamic stores.
-An explicit ``taken`` mask (not NEG_INF overwriting) breaks ties:
+An explicit ``taken`` mask (not NEG_INF overwriting, int32 because
+Mosaic carries no bool vector through a loop) breaks ties:
 once every untaken score IS ``NEG_INF``, masked re-selection would
 loop on one position forever, while the taken mask keeps emitting
 fresh indices in ascending order.
@@ -87,19 +88,22 @@ def _topk_kernel(scores_ref, cand_v_ref, cand_i_ref, *,
     base = i * block_rows * LANES                  # global index offset
 
     def round_j(j, carry):
+        # ``taken`` is an int32 0/1 mask: Mosaic cannot carry a bool
+        # vector through a loop (scf.for does not legalize).
         taken, cand_v, cand_i = carry
-        masked = jnp.where(taken, NEG_INF, scores)
+        untaken = taken == 0
+        masked = jnp.where(untaken, scores, NEG_INF)
         m = jnp.max(masked)
         # winner = minimum flat index among untaken maxima (tie-break)
-        at_max = (masked == m) & ~taken
+        at_max = (masked == m) & untaken
         sel = jnp.min(jnp.where(at_max, flat_in, _INT_MAX))
-        taken = taken | (flat_in == sel)
+        taken = jnp.where(flat_in == sel, 1, taken)
         write = flat_out == j
         cand_v = jnp.where(write, m, cand_v)
         cand_i = jnp.where(write, base + sel, cand_i)
         return taken, cand_v, cand_i
 
-    taken0 = jnp.zeros((block_rows, LANES), jnp.bool_)
+    taken0 = jnp.zeros((block_rows, LANES), jnp.int32)
     v0 = jnp.full((rows, LANES), NEG_INF, jnp.float32)
     i0 = jnp.full((rows, LANES), _INT_MAX, jnp.int32)
     _, cand_v, cand_i = jax.lax.fori_loop(
@@ -142,7 +146,7 @@ def topk_select(scores: jnp.ndarray, k: int, *,
                                kb=kb)
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=topk_select_vmem_bytes(block_rows, kb))
     cand_v, cand_i = pl.pallas_call(
         kernel,

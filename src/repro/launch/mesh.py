@@ -13,21 +13,32 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the partitioner propagates
+    shardings through the model code. (Left unspecified, axes are
+    Explicit, and sharding-in-types rejects the embedding gathers and
+    head reshapes the models do on sharded operands.)"""
     import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...] = (1, 1),
                    axes: Tuple[str, ...] = ("data", "model")):
-    """Small mesh over whatever local devices exist (tests/smoke)."""
+    """Mesh over the local devices: (1, 1) for tests and one chip,
+    (1, 4) for the model-parallel evaluator on a four-chip host."""
     import jax
     n = int(np.prod(shape))
     if len(jax.devices()) < n:
         raise ValueError(f"need {n} devices, have {len(jax.devices())}")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_from_devices(devices: Sequence, shape: Tuple[int, ...],

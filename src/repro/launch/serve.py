@@ -17,12 +17,22 @@ deterministic N-doc Zipf corpus is indexed into ``--index-shards``
 doc-partitions owned by replicas through the consistent-hash ring, and
 requests arrive as *raw query strings* — parse -> BM25 -> Pallas top-k
 picks each candidate set — instead of pre-retrieved key arrays.
+
+The evaluator runs at its smoke width (CPU runs, CI) unless
+``--full-width`` builds the published configuration. The run exits
+non-zero when a batch had to be rescued from the prior
+(``executor_error``): a serving path that only answers from the prior
+is broken, however fast its scoreboard looks. ``chip_smoke.py`` drives
+this same code path in-process through :func:`launch`, :func:`serve`
+and :func:`report`.
 """
 from __future__ import annotations
 
 import argparse
 import sys
 import time
+from dataclasses import dataclass
+from typing import Any, Callable, List, Optional
 
 import numpy as np
 
@@ -101,13 +111,12 @@ it here. Production-scale backbones stay on the fused hot path via:
                                           _backlog_batches /
                                           _latency_frac tune the
                                           no-flap guarantees
-  TrustIRConfig.cache_ways_leading        Trust-DB probe cache layout:
-                                          True (default) tiles VMEM
-                                          (ways, slots) so the
-                                          multi-way probe reads one
-                                          (8,128) block per way;
+  TrustIRConfig.cache_ways_leading        Trust-DB cache layout:
+                                          True (default) stores
+                                          (ways, slots) so the probe
+                                          gathers one row per way;
                                           False restores the legacy
-                                          row-slab layout
+                                          (slots, ways) layout
   TrustIRConfig.fanout_adaptive_quorum    let the coordinator walk
                                           fanout_quorum_k with the
                                           offered regime (tighten
@@ -119,11 +128,14 @@ it here. Production-scale backbones stay on the fused hot path via:
 """
 
 
-def main() -> int:
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     p = argparse.ArgumentParser(
         description=__doc__, epilog=_EPILOG,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     p.add_argument("--arch", default="smollm-135m")
+    p.add_argument("--full-width", action="store_true",
+                   help="build the evaluator at its published width "
+                        "(default: the smoke width, for CPU runs)")
     p.add_argument("--n-requests", type=int, default=10)
     p.add_argument("--deadline-ms", type=float, default=50.0)
     p.add_argument("--overload-deadline-ms", type=float, default=100.0)
@@ -231,36 +243,45 @@ def main() -> int:
                         "replica r0's shard (straggler injection demo "
                         "for --quorum-k/--shard-hedge-ms; 0 = off)")
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
+    return p.parse_args(argv)
 
-    import jax.numpy as jnp
-    from repro.cluster import ClusterConfig, ClusterCoordinator
+
+@dataclass
+class Launch:
+    """A booted, jit-warm serving stack: what :func:`serve` drives and
+    :func:`report` summarizes (``chip_smoke.py`` inspects it too)."""
+    args: argparse.Namespace
+    cfg: Any                      # TrustIRConfig
+    evaluate: Callable            # serving.evaluators.Evaluator
+    make_features: Callable
+    eng: Any                      # ServingEngine (--sync) or the fleet
+    retrieval: Any = None         # retrieval.CorpusRetrieval
+    queries: Any = None           # retrieval.ZipfQueryModel
+
+
+def calibrate(args: argparse.Namespace):
+    """Build the evaluator and size the shedder to its measured rate:
+    ``(evaluate, make_features, feature_sharding, rate, cfg)``."""
     from repro.configs.base import TrustIRConfig
-    from repro.core.adaptive import AdaptiveWeightController
-    from repro.scheduling import Priority
-    from repro.serving.engine import ServingEngine
     from repro.serving.evaluators import (make_evaluator,
                                           make_sharded_evaluator)
 
+    smoke = not args.full_width
     feature_sharding = None
     if args.sharded:
         if args.drain_mode != "fused":
             raise SystemExit("--sharded shards the fused evaluator "
                              "window; add --drain-mode fused")
-        se = make_sharded_evaluator(args.arch, smoke=True)
+        se = make_sharded_evaluator(args.arch, smoke=smoke)
         ev, mk = se.evaluate, se.make_features
         feature_sharding = se.feature_sharding
     else:
-        ev, mk = make_evaluator(args.arch, smoke=True)
-
-    def evaluate(chunk):
-        return np.asarray(ev({k: jnp.asarray(v)
-                              for k, v in chunk.items()}))
+        ev, mk = make_evaluator(args.arch, smoke=smoke)
 
     feats64 = mk(64)
-    evaluate(feats64)
+    np.asarray(ev(feats64))
     t0 = time.perf_counter()
-    evaluate(feats64)
+    np.asarray(ev(feats64))
     rate = 64 / max(time.perf_counter() - t0, 1e-6)
     dl = args.deadline_ms / 1e3
     odl = args.overload_deadline_ms / 1e3
@@ -290,6 +311,7 @@ def main() -> int:
     print(f"{args.arch}: {rate:,.0f} items/s -> Ucap={cfg.u_capacity} "
           f"Uthr={cfg.u_threshold} deadline={dl * 1e3:.0f}ms "
           f"(overload {odl * 1e3:.0f}ms)"
+          + (" [full width]" if args.full_width else " [smoke width]")
           + (" [adaptive]" if args.adaptive else "")
           + (" [sync]" if args.sync
              else f" [scheduled x{n_rep} replica(s)]")
@@ -299,14 +321,25 @@ def main() -> int:
           + f" [drain={args.drain_mode}"
           + (f" depth={cfg.pipeline_depth}]"
              if args.drain_mode == "fused" else "]"))
+    return ev, mk, feature_sharding, rate, cfg
 
-    def evaluate_batch(chunk):            # jax-traceable (fused drain)
-        return ev(chunk)
 
-    if args.trace > 0:
-        if args.sync:
-            raise SystemExit("--trace drives a fleet; drop --sync")
-        return _run_trace(args, cfg, rate)
+def launch(args: argparse.Namespace) -> Launch:
+    """Calibrate, attach the retrieval front end (``--corpus``), boot
+    the engine or fleet, and warm every jit shape the run will use."""
+    import jax.numpy as jnp
+    from repro.cluster import ClusterConfig, ClusterCoordinator
+    from repro.core.adaptive import AdaptiveWeightController
+    from repro.serving.engine import ServingEngine
+
+    ev, mk, feature_sharding, _, cfg = calibrate(args)
+    odl = args.overload_deadline_ms / 1e3
+    n_rep = max(args.replicas, 1)
+    elastic = args.max_replicas > 0
+
+    def evaluate(chunk):                  # host chunk-loop protocol
+        return np.asarray(ev({k: jnp.asarray(v)
+                              for k, v in chunk.items()}))
 
     retrieval = queries = fanout_model = None
     if args.corpus > 0:
@@ -349,7 +382,7 @@ def main() -> int:
             retriever = retrieval.searcher(
                 [retrieval.build_shard(range(cfg.index_partitions))])
         eng = ServingEngine(cfg, evaluate, drain_mode=args.drain_mode,
-                            evaluate_batch=evaluate_batch,
+                            evaluate_batch=ev,
                             retriever=retriever,
                             feature_sharding=feature_sharding)
         if args.adaptive:
@@ -367,7 +400,7 @@ def main() -> int:
                 forecast=args.forecast,
                 warmup_lead_s=max(args.warmup_lead_s, 0.0)),
             drain_mode=args.drain_mode,
-            evaluate_batch=evaluate_batch,
+            evaluate_batch=ev,
             retrieval=retrieval,
             fanout_model=fanout_model,
             feature_sharding=feature_sharding)
@@ -375,16 +408,11 @@ def main() -> int:
             for rep in eng.replicas:
                 rep.engine.shedder.adaptive = AdaptiveWeightController()
 
-    r = np.random.default_rng(args.seed)
-    sizes = np.clip(r.zipf(1.4, size=args.n_requests) * 64, 64, 4096)
-    # Priority mix: mostly NORMAL, some HIGH/CRITICAL, a LOW tail.
-    prio_choices = [Priority.CRITICAL, Priority.HIGH, Priority.NORMAL,
-                    Priority.LOW]
-    prios = r.choice(4, size=args.n_requests, p=[0.1, 0.2, 0.5, 0.2])
     warm_shedders = ([eng.shedder] if args.sync
                      else [rep.engine.shedder for rep in eng.replicas])
     if queries is None:
-        for n in sorted(set(int(s) for s in sizes)):  # warm jit per size
+        _, sizes, _ = _request_stream(args, args.n_requests)
+        for n in sorted(set(int(s) for s in sizes)):  # warm jit
             for shedder in warm_shedders:  # every replica compiles NOW
                 shedder.process(
                     np.arange(10**6, 10**6 + n, dtype=np.uint32),
@@ -415,11 +443,45 @@ def main() -> int:
             rep.engine.drain()
         eng.drain()                  # collect warm responses, then drop
     eng.completed.clear()
+    return Launch(args=args, cfg=cfg, evaluate=ev, make_features=mk,
+                  eng=eng, retrieval=retrieval, queries=queries)
+
+
+def _request_stream(args: argparse.Namespace, n_req: int):
+    """``(rng, sizes, priority indices)`` of the request loop, all
+    drawn from ``--seed`` (the rng goes on to draw the buckets)."""
+    r = np.random.default_rng(args.seed)
+    sizes = np.clip(r.zipf(1.4, size=n_req) * 64, 64, 4096)
+    # Priority mix: mostly NORMAL, some HIGH/CRITICAL, a LOW tail.
+    prios = r.choice(4, size=n_req, p=[0.1, 0.2, 0.5, 0.2])
+    return r, sizes, prios
+
+
+def serve(run: Launch, query_strings: Optional[List[str]] = None
+          ) -> Optional[List[str]]:
+    """The request loop. In corpus mode it serves ``query_strings``
+    (default: ``--n-requests`` draws from the query model) and returns
+    the query strings it served; otherwise synthetic pre-retrieved
+    requests, returning None."""
+    from repro.scheduling import Priority
+
+    args, eng, queries, mk = run.args, run.eng, run.queries, \
+        run.make_features
+    odl = args.overload_deadline_ms / 1e3
+    n_rep = max(args.replicas, 1)
+    n_req = (len(query_strings) if query_strings is not None
+             else args.n_requests)
+    r, sizes, prios = _request_stream(args, n_req)
+    prio_choices = [Priority.CRITICAL, Priority.HIGH, Priority.NORMAL,
+                    Priority.LOW]
+    served: List[str] = []
 
     for i, n in enumerate(int(s) for s in sizes):
         prio = prio_choices[int(prios[i])]
         if queries is not None:
-            q = queries.sample()
+            q = (query_strings[i] if query_strings is not None
+                 else queries.sample())
+            served.append(q)
             if args.sync:
                 rid = eng.enqueue_query(q, slo_s=odl * 2.5,
                                         priority=prio)
@@ -459,6 +521,15 @@ def main() -> int:
                 eng.drain(1)                 # one batch (or round)
     if not args.sync:
         eng.drain()
+    return served if queries is not None else None
+
+
+def report(run: Launch) -> int:
+    """Print the responses, stats and P50/P99 scoreboard; the exit
+    code is non-zero when any batch was rescued by the executor."""
+    args, eng, retrieval = run.args, run.eng, run.retrieval
+    st = eng.scheduler_stats()
+    if not args.sync:
         for resp in eng.completed:
             s = resp.shed
             flag = ("REJECTED " + resp.reason if not resp.admitted
@@ -468,7 +539,6 @@ def main() -> int:
                   f"{resp.latency_s * 1e3:7.1f} ms  "
                   f"eval {s.n_evaluated:>5} cached {s.n_cached:>5} "
                   f"prior {s.n_prior:>5} {flag}")
-        st = eng.scheduler_stats()
         print(f"scheduler: {st['n_batches']} batches, mean fill "
               f"{st['mean_batch_fill']:.0f} items, "
               f"{st['n_rejected']} rejected {st['rejected_by_reason']}, "
@@ -511,7 +581,25 @@ def main() -> int:
     print(f"P50 {board['p50_s'] * 1e3:.1f} ms  P99 "
           f"{board['p99_s'] * 1e3:.1f} ms  SLO met "
           f"{100 * board['slo_met_frac']:.0f}%")
+    if st["n_executor_errors"]:
+        print(f"FAIL: {st['n_executor_errors']} batch(es) rescued from "
+              f"the prior by the executor (executor_error)")
+        return 1
     return 0
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    args = parse_args(argv)
+    enable_compile_cache()
+    if args.trace > 0:
+        if args.sync:
+            raise SystemExit("--trace drives a fleet; drop --sync")
+        *_, rate, cfg = calibrate(args)
+        return _run_trace(args, cfg, rate)
+    run = launch(args)
+    serve(run)
+    return report(run)
 
 
 def _run_trace(args, cfg, rate: float) -> int:

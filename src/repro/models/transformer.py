@@ -376,18 +376,66 @@ def lm_loss(params: Dict, cfg: TransformerConfig, tokens: jnp.ndarray,
     return loss, metrics
 
 
+def target_logprobs(params: Dict, cfg: TransformerConfig, x: jnp.ndarray,
+                    targets: jnp.ndarray, vocab_chunk: int = 2048
+                    ) -> jnp.ndarray:
+    """``log p(targets)`` (B, S) from final hidden states x (B, S, D).
+
+    The logsumexp runs over vocabulary chunks of ``vocab_chunk`` rows
+    (a ``lax.scan`` with a running max and sum), so only (B, S,
+    vocab_chunk) logits exist at a time, never (B, S, V): at the
+    published smollm-135m width and a 4096-item evaluator batch the
+    full f32 logits alone would be ~25 GB. Each chunk's logits are the
+    same values the full unembedding gives (compute dtype, softcap,
+    then f32), and the target's logit is picked from its chunk."""
+    B, S, D = x.shape
+    V = cfg.vocab_size
+    vc = min(vocab_chunk, V)
+    n_c = -(-V // vc)
+    pad = n_c * vc - V
+    if cfg.tie_embeddings:
+        w = params["embed"]["table"]                          # (V, D)
+    else:
+        w = params["unembed"]["w"].T                          # (V, D)
+    w = jnp.pad(w, ((0, pad), (0, 0))).reshape(n_c, vc, D)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, vc), 2)
+
+    def chunk(carry, xs):
+        m, s, tgt = carry
+        w_c, base = xs
+        logits = x @ w_c.astype(x.dtype).T                   # (B, S, vc)
+        if cfg.final_logit_softcap > 0:
+            logits = L.softcap(logits, cfg.final_logit_softcap)
+        logits = logits.astype(jnp.float32)
+        ids = base + col
+        logits = jnp.where(ids < V, logits, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+        s = (s * jnp.exp(m - m_new)
+             + jnp.sum(jnp.exp(logits - m_new[..., None]), axis=-1))
+        tgt = tgt + jnp.sum(
+            jnp.where(ids == targets[..., None], logits, 0.0), axis=-1)
+        return (m_new, s, tgt), None
+
+    init = (jnp.full((B, S), -jnp.inf, jnp.float32),
+            jnp.zeros((B, S), jnp.float32),
+            jnp.zeros((B, S), jnp.float32))
+    bases = jnp.arange(n_c, dtype=jnp.int32) * vc
+    (m, s, tgt), _ = jax.lax.scan(chunk, init, (w, bases))
+    return tgt - (m + jnp.log(s))
+
+
 def score_tokens(params: Dict, cfg: TransformerConfig, tokens: jnp.ndarray,
-                 mask: Optional[jnp.ndarray] = None, q_chunk: int = 1024
-                 ) -> jnp.ndarray:
+                 mask: Optional[jnp.ndarray] = None, q_chunk: int = 1024,
+                 vocab_chunk: int = 2048) -> jnp.ndarray:
     """Sequence log-likelihood score, the LM trust-evaluator head.
 
     Returns per-sequence mean token logprob (B,) — mapped to a
-    trustworthiness value by the core pipeline.
+    trustworthiness value by the core pipeline. The vocabulary
+    logsumexp is chunked (:func:`target_logprobs`).
     """
-    logits, _ = forward(params, cfg, tokens[:, :-1], q_chunk=q_chunk)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    tgt = tokens[:, 1:]
-    tok_lp = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+    x, _ = hidden_states(params, cfg, tokens[:, :-1], q_chunk=q_chunk)
+    tok_lp = target_logprobs(params, cfg, x, tokens[:, 1:],
+                             vocab_chunk=vocab_chunk)
     if mask is not None:
         m = mask[:, 1:].astype(jnp.float32)
         return jnp.sum(tok_lp * m, axis=-1) / jnp.maximum(

@@ -152,11 +152,11 @@ candidate sets start at *arrive*):
        |                                    fold-back — staged (host->
        |                                    device transfer) then
        |                                    dispatched, both async; the
-       |                                    Trust-DB probe walks a
-       |                                    ways-LEADING cache tile
-       |                                    (one (8,128) VMEM block per
-       |                                    way instead of a strided
-       |                                    row slab); a mesh-sharded
+       |                                    Trust-DB probe is an XLA
+       |                                    gather of each key's set
+       |                                    (cache in HBM) feeding the
+       |                                    kernel (8,128) per-way
+       |                                    candidate blocks; a mesh-sharded
        |                                    evaluator (serving.
        |                                    evaluators.
        |                                    make_sharded_evaluator)

@@ -2,7 +2,9 @@
 into the shedder's ``evaluate_chunk(features) -> scores`` protocol, making
 the paper's algorithm arch-agnostic (DESIGN.md §4).
 
-Each factory returns (evaluate_chunk, make_features) where
+Each factory returns (evaluate, make_features): ``evaluate`` is an
+:class:`Evaluator` — a jitted ``apply(params, features) -> scores``
+bound to its parameters, callable as ``evaluate(features)`` — and
 ``make_features(n, seed)`` synthesizes evaluator inputs for n items
 (documents/candidates) with leading dim n.
 
@@ -29,11 +31,28 @@ from repro.configs import get_config
 from repro.configs.base import GNNConfig, RecsysConfig, TransformerConfig
 
 
+class Evaluator:
+    """A trust evaluator: ``apply(params, features) -> (n,) scores``
+    and the parameters it runs with. Calling it scores one feature
+    batch. The fused drain passes ``params`` into its jitted step as an
+    argument (``core.fused_shedder``): a closure over them would fold
+    every weight into the compiled step as a constant."""
+
+    def __init__(self, apply: Callable, params: Any):
+        self.apply = apply
+        self.params = params
+        self._jitted = jax.jit(apply)
+
+    def __call__(self, features) -> jnp.ndarray:
+        return self._jitted(self.params, features)
+
+
 def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
                    trust_scale: float = 5.0, doc_len: int = 32,
                    place_params: Optional[Callable] = None
-                   ) -> Tuple[Callable, Callable]:
-    """``place_params(params, cfg) -> params`` (optional) re-homes the
+                   ) -> Tuple[Evaluator, Callable]:
+    """``smoke=False`` builds the published-width configuration.
+    ``place_params(params, cfg) -> params`` (optional) re-homes the
     freshly initialized parameters — the mesh-sharding hook
     :func:`make_sharded_evaluator` uses; identity when omitted."""
     cfg = get_config(arch_id, smoke=smoke)
@@ -44,8 +63,7 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
         from repro.models import transformer as T
         params = _place(T.init_params(key, cfg), cfg)
 
-        @jax.jit
-        def evaluate(chunk: Dict) -> jnp.ndarray:
+        def apply(params, chunk: Dict) -> jnp.ndarray:
             # mean token logprob -> squashed to [0, trust_scale]
             lp = T.score_tokens(params, cfg, chunk["tokens"],
                                 q_chunk=doc_len)
@@ -57,15 +75,14 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
             return {"tokens": r.integers(0, cfg.vocab_size,
                                          size=(n, doc_len)
                                          ).astype(np.int32)}
-        return evaluate, make_features
+        return Evaluator(apply, params), make_features
 
     if isinstance(cfg, GNNConfig):
         from repro.models import gnn as G
         params = _place(G.init_params(key, cfg), cfg)
         deg = 8
 
-        @jax.jit
-        def evaluate(chunk: Dict) -> jnp.ndarray:
+        def apply(params, chunk: Dict) -> jnp.ndarray:
             # per-chunk star subgraphs: each URL node + its neighbors;
             # trust propagates from neighbor features (TrustRank-style)
             x = chunk["x"].reshape(-1, cfg.d_feat)       # (n*(deg+1), F)
@@ -85,15 +102,14 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
             src = (base + 1 + np.arange(deg)[None]).astype(np.int32)
             dst = np.broadcast_to(base, (n, deg)).astype(np.int32)
             return {"x": x, "edge_src": src, "edge_dst": dst}
-        return evaluate, make_features
+        return Evaluator(apply, params), make_features
 
     if isinstance(cfg, RecsysConfig):
         if cfg.model == "dlrm":
             from repro.models.recsys import dlrm as Mdl
             params = _place(Mdl.init_params(key, cfg), cfg)
 
-            @jax.jit
-            def evaluate(chunk: Dict) -> jnp.ndarray:
+            def apply(params, chunk: Dict) -> jnp.ndarray:
                 return Mdl.relevance_scores(params, cfg, chunk["dense"],
                                             chunk["sparse"],
                                             trust_scale=trust_scale)
@@ -107,14 +123,13 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
                         [r.integers(0, t.vocab, size=n)
                          for t in cfg.tables], axis=1).astype(np.int32),
                 }
-            return evaluate, make_features
+            return Evaluator(apply, params), make_features
 
         if cfg.model == "bst":
             from repro.models.recsys import bst as Mdl
             params = _place(Mdl.init_params(key, cfg), cfg)
 
-            @jax.jit
-            def evaluate(chunk: Dict) -> jnp.ndarray:
+            def apply(params, chunk: Dict) -> jnp.ndarray:
                 return Mdl.relevance_scores(params, cfg, chunk["hist"],
                                             chunk["target"],
                                             chunk["other"],
@@ -132,14 +147,13 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
                          for t in cfg.tables[1:]], axis=1
                     ).astype(np.int32),
                 }
-            return evaluate, make_features
+            return Evaluator(apply, params), make_features
 
         if cfg.model == "two_tower":
             from repro.models.recsys import two_tower as Mdl
             params = _place(Mdl.init_params(key, cfg), cfg)
 
-            @jax.jit
-            def evaluate(chunk: Dict) -> jnp.ndarray:
+            def apply(params, chunk: Dict) -> jnp.ndarray:
                 q = {"user_id": chunk["user_id"][:1],
                      "user_feats": chunk["user_feats"][:1]}
                 s = Mdl.retrieval_scores(params, cfg, q,
@@ -158,14 +172,13 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
                     "item_feats": r.integers(0, cfg.tables[3].vocab,
                                              size=(n, 8)).astype(np.int32),
                 }
-            return evaluate, make_features
+            return Evaluator(apply, params), make_features
 
         if cfg.model == "mind":
             from repro.models.recsys import mind as Mdl
             params = _place(Mdl.init_params(key, cfg), cfg)
 
-            @jax.jit
-            def evaluate(chunk: Dict) -> jnp.ndarray:
+            def apply(params, chunk: Dict) -> jnp.ndarray:
                 return Mdl.relevance_scores(params, cfg, chunk["hist"],
                                             chunk["hist_mask"],
                                             chunk["item"],
@@ -180,7 +193,7 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
                     "hist_mask": np.ones((n, cfg.hist_len), np.float32),
                     "item": r.integers(0, iv, size=n).astype(np.int32),
                 }
-            return evaluate, make_features
+            return Evaluator(apply, params), make_features
 
     raise ValueError(f"no evaluator for {arch_id}")
 
@@ -191,7 +204,7 @@ class ShardedEvaluator(NamedTuple):
     ``make_features``, the ``feature_sharding`` callable to hand to
     :class:`~repro.core.fused_shedder.FusedLoadShedder` (and through
     ``ServingEngine(feature_sharding=...)``), and the mesh itself."""
-    evaluate: Callable
+    evaluate: Evaluator
     make_features: Callable
     feature_sharding: Callable
     mesh: Any

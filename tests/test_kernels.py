@@ -153,23 +153,18 @@ def test_shed_partition_lane_tiled_ragged_tails(n, n_valid,
 
 
 def test_shed_partition_vmem_budget_fits_production_config():
-    """The measured VMEM claim: the production Trust-DB (65536 slots x
-    4 ways, keys + values, tile-padding honest) plus double-buffered
-    (8,128) blocks must fit comfortably under the ~16 MiB per-core
-    budget."""
+    """The VMEM claim at the production config (4 ways, (8,128)
+    blocks): the Trust DB is probed by an XLA gather and stays in HBM,
+    so the kernel claims only its double-buffered blocks — far under
+    the ~16 MiB per-core budget, at any cache size."""
     from repro.kernels.shed_partition import shed_partition_vmem_bytes
-    budget = shed_partition_vmem_bytes(65536, 4)
-    # Ways-leading (4, 65536): ways pad to the 8-sublane f32 tile, so
-    # the resident claim is 2 * 8 * 65536 * 4 B = 4 MiB (+ blocks and
-    # slack) — ~4.2 MiB measured.
-    assert budget < 5 * (1 << 20)
-    assert budget >= 2 * 8 * 65536 * 4     # never under-claims the DB
-    # The legacy slots-leading layout pads ways to 128 LANES — a 32 MiB
-    # resident claim that cannot lower at the production config. The
-    # retile is what makes the production cache fit.
-    legacy = shed_partition_vmem_bytes(65536, 4, ways_leading=False)
-    assert legacy > 16 * (1 << 20)
-    assert budget < legacy // 7
+    budget = shed_partition_vmem_bytes(4)
+    # keys, valid, 4 candidate keys + 4 values in; tier, cval, rank out
+    blocks = (5 + 2 * 4) * 8 * 128 * 4
+    assert budget >= 2 * blocks            # never under-claims a block
+    assert budget < 1 << 20
+    # taller blocks scale the claim linearly, still nowhere near VMEM
+    assert shed_partition_vmem_bytes(4, block_rows=64) < 16 * (1 << 20)
 
 
 @pytest.mark.parametrize("cache_mode", ["all_miss", "all_hit",

@@ -16,7 +16,6 @@ def test_ep_moe_matches_dense_scatter():
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import MoEConfig
         from repro.models import moe as MO
-        from repro.distribution.constraints import use_mesh
 
         cfg = MoEConfig(n_experts=8, top_k=2, d_expert=32,
                         capacity_factor=8.0, dispatch="dense_scatter")
@@ -29,7 +28,7 @@ def test_ep_moe_matches_dense_scatter():
         mesh = jax.make_mesh((2, 4), ("data", "model"))
         from jax.sharding import NamedSharding
         S = lambda *spec: NamedSharding(mesh, P(*spec))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             ep = jax.jit(lambda p, x: MO.moe_apply_ep(
                 p, x, cfg, compute_dtype=jnp.float32)[0],
                 in_shardings=(S(), S("data", None)),
@@ -38,14 +37,14 @@ def test_ep_moe_matches_dense_scatter():
         assert err < 1e-4, err
         # gradient parity through the EP region
         def loss_ep(p):
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 out = jax.jit(lambda p: MO.moe_apply_ep(
                     p, x, cfg, compute_dtype=jnp.float32)[0])(p)
             return jnp.sum(out ** 2)
         def loss_ref(p):
             return jnp.sum(MO.moe_apply(p, x, cfg,
                                         compute_dtype=jnp.float32)[0] ** 2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             g_ep = jax.jit(jax.grad(lambda p: jnp.sum(MO.moe_apply_ep(
                 p, x, cfg, compute_dtype=jnp.float32)[0] ** 2)))(p)
         g_ref = jax.grad(loss_ref)(p)

@@ -77,6 +77,29 @@ def test_prefill_score_matches_score_tokens():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("arch,vocab_chunk", [
+    ("smollm-135m", 64),       # tied embeddings, chunk divides V
+    ("smollm-135m", 96),       # ragged last chunk (masked padding rows)
+    ("qwen2.5-14b", 100),      # untied unembedding
+    ("gemma2-2b", 64),         # final-logit softcap per chunk
+])
+def test_chunked_target_logprobs_match_full_log_softmax(arch, vocab_chunk):
+    """The vocab-chunked logsumexp equals log_softmax over the full
+    (B, S, V) logits, target by target."""
+    cfg = get_config(arch, smoke=True)
+    params = T.init_params(KEY, cfg)
+    toks = jax.random.randint(KEY, (3, 12), 0, cfg.vocab_size)
+    logits, _ = T.forward(params, cfg, toks[:, :-1])
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    want = jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1)[..., 0]
+    x, _ = T.hidden_states(params, cfg, toks[:, :-1])
+    got = T.target_logprobs(params, cfg, x, toks[:, 1:],
+                            vocab_chunk=vocab_chunk)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("arch", ["smollm-135m", "qwen3-moe-30b-a3b"])
 def test_scan_matches_unrolled(arch):
     """scan_layers=True must be numerically identical to the unrolled
