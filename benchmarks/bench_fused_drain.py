@@ -38,15 +38,6 @@ executor's chunk-granular clock lands on the identical grant — and the
 (8,128)-tiled kernel pads its ragged tails internally), trust matches
 to float tolerance (batched vs chunked matmul reassociation), and the
 no-item-dropped property holds on both paths.
-
-A third phase (``_roofline_phase``) re-runs the serving loop with REAL
-mesh-sharded model evaluators (transformer + DLRM minimum, via
-``serving.evaluators.make_sharded_evaluator``) and records one
-roofline point per arch — FLOPs/item, bytes/item and arithmetic
-intensity from XLA's cost analysis of the evaluator program that
-actually ran — gating fused >= host and adaptive-depth >= best-static
-items/s in the evaluator-dominated regime the linear-probe phases
-cannot reach.
 """
 from __future__ import annotations
 
@@ -184,8 +175,7 @@ def _throughput_phase(n_requests: int, items_per_req: int,
     # sync path eats every scheduler hiccup serially. So the full
     # target binds on accelerator backends; on cpu the sweep degrades
     # to a no-overhead check — the window must not COST throughput
-    # (>= 0.9x) — and the heavyweight-evaluator roofline phase carries
-    # the binding fused/adaptive gates.
+    # (>= 0.9x).
     if 1 in sweep and len(sweep) > 1:
         import jax
         best = max((d for d in sweep if d > 1),
@@ -252,160 +242,9 @@ def _parity_phase(out: Dict) -> None:
     out["no_drop_ok"] = bool(no_drop_ok)
 
 
-def _roofline_phase(out: Dict, quick: bool = False,
-                    archs=("smollm-135m", "dlrm-mlperf"),
-                    full: bool = False) -> None:
-    """Heavyweight-evaluator sweep (ISSUE 10 tentpole layer 4): drive
-    the serving loop with REAL model evaluators — a transformer and a
-    DLRM at minimum — mesh-sharded through
-    ``serving.evaluators.make_sharded_evaluator``, and record a
-    roofline point per arch: FLOPs/item and bytes/item from XLA's cost
-    analysis of the exact evaluator program that ran, arithmetic
-    intensity, and the achieved FLOP/s of the best drain config.
-
-    ``full=False`` (the default; CI and CPU containers) runs the smoke
-    model configs — the production (``smoke=False``) configs are ~40 s
-    per forward on a host CPU, so ``--roofline-full`` gates them to
-    real accelerators. The drain paths, sharding placement, gates and
-    recorded intensity math are identical either way; only the model
-    size changes, and each row is labeled with the config that ran.
-
-    Gates (auto-collected by ``benchmarks/run.py`` as ``*_ok``): when
-    the evaluator dominates the batch (``eval_frac > 0.5`` — true for
-    every real model here; the linear-probe throughput phase above is
-    the opposite regime), the fused window must hold ``>= 0.95x`` host
-    items/s per arch, and adaptive depth must hold ``>= 0.9x`` the
-    best static depth's items/s with p99 no worse than ``1.25x`` the
-    static depth it REPLACES (its clamp, the deepest static) —
-    responses deliberately reside in a depth-k window, so a shallower
-    static depth's tail is not the meaningful guard (same reasoning as
-    the depth sweep's ``depth_p99_ok``); adaptive starts at the clamp
-    and only shallows on latency evidence, so it must not lose what
-    the static window won on either axis.
-    """
-    import dataclasses
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs.base import TrustIRConfig
-    from repro.scheduling import SchedulerConfig
-    from repro.serving.engine import ServingEngine
-    from repro.serving.evaluators import make_sharded_evaluator
-
-    # Enough batches to denoise: fast (recsys) evaluators finish a
-    # 128-item batch in ~2 ms on a host CPU, so a small sweep would
-    # measure scheduler jitter, not the drain configs.
-    n_requests = 48 if quick else 96
-    items_per_req, bat = 32, 128
-    depths = (1, 2, 4)
-    base = TrustIRConfig(u_capacity=4096, u_threshold=2048,
-                         deadline_s=0.5, overload_deadline_s=1.0,
-                         chunk_size=32, cache_slots=8192)
-    sched_cfg = SchedulerConfig(max_batch_items=bat)
-    rows: Dict[str, Dict] = {}
-
-    def _reqs(se, n_reqs, key_offset):
-        reqs = []
-        for i in range(n_reqs):
-            b0 = key_offset + i * 100_000 + 1
-            keys = np.arange(b0, b0 + items_per_req, dtype=np.uint32)
-            buckets = (keys % 64).astype(np.int32)
-            reqs.append((keys, buckets,
-                         se.make_features(items_per_req, fseed=i)))
-        return reqs
-
-    def _run(se, ev_np, mode, depth, adaptive, rep_off):
-        cfg = dataclasses.replace(
-            base, pipeline_depth=depth, adaptive_depth=adaptive)
-        eng = ServingEngine(cfg, ev_np, sched_cfg=sched_cfg,
-                            drain_mode=mode, evaluate_batch=se.evaluate,
-                            feature_sharding=(se.feature_sharding
-                                              if mode == "fused"
-                                              else None))
-        _run_stream(eng, _reqs(se, 8, 900_000_000 + rep_off), bat)
-        best = None
-        for rep in range(3):
-            eng.completed.clear()
-            wall = _run_stream(
-                eng, _reqs(se, n_requests,
-                           rep_off + rep * 50_000_000), bat)
-            assert len({r.request_id for r in eng.completed}) \
-                == len(eng.completed) == n_requests
-            s = eng.slo_stats()
-            row = {"items_per_s": n_requests * items_per_req / wall,
-                   "p99_s": s["p99_s"]}
-            if best is None or row["items_per_s"] > best["items_per_s"]:
-                best = row
-        return best
-
-    for ai, arch in enumerate(archs):
-        se = make_sharded_evaluator(arch, smoke=not full)
-
-        def ev_np(chunk, _se=se):
-            return np.asarray(_se.evaluate(
-                jax.tree.map(jnp.asarray, chunk)))
-
-        feats = jax.device_put(se.make_features(bat),
-                               se.feature_sharding(se.make_features(bat)))
-        params = se.evaluate.params
-        compiled = jax.jit(se.evaluate.apply).lower(params, feats).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):      # older jax returns [dict]
-            ca = ca[0] if ca else {}
-        flops_b = float((ca or {}).get("flops", 0.0))
-        bytes_b = float((ca or {}).get("bytes accessed", 0.0))
-        jax.block_until_ready(compiled(params, feats))  # warm the AOT exec
-        t0 = time.perf_counter()
-        for _ in range(3):
-            jax.block_until_ready(compiled(params, feats))
-        eval_s = (time.perf_counter() - t0) / 3
-
-        off = ai * 1_000_000_000
-        host = _run(se, ev_np, "host", 1, False, off)
-        static = {d: _run(se, ev_np, "fused", d, False,
-                          off + (d + 1) * 10_000_000) for d in depths}
-        best_d = max(static, key=lambda d: static[d]["items_per_s"])
-        adaptive = _run(se, ev_np, "fused", max(depths), True,
-                        off + 90_000_000)
-
-        fused_ips = static[best_d]["items_per_s"]
-        batch_s = bat / fused_ips
-        eval_frac = min(eval_s / batch_s, 1.0) if batch_s > 0 else 0.0
-        dominated = eval_frac > 0.5
-        fused_ok = (not dominated) or fused_ips >= host["items_per_s"] * 0.95
-        adaptive_ok = (not dominated) or (
-            adaptive["items_per_s"] >= fused_ips * 0.9
-            and adaptive["p99_s"]
-            <= static[max(depths)]["p99_s"] * 1.25)
-        rows[arch] = {
-            "config": "production" if full else "smoke",
-            "flops_per_item": flops_b / bat,
-            "bytes_per_item": bytes_b / bat,
-            "arithmetic_intensity": (flops_b / bytes_b
-                                     if bytes_b else 0.0),
-            "eval_s_per_batch": eval_s,
-            "eval_frac": eval_frac,
-            "eval_dominated": bool(dominated),
-            "host": host,
-            "static": {str(d): r for d, r in static.items()},
-            "best_static_depth": best_d,
-            "adaptive": adaptive,
-            "achieved_flops_per_s": flops_b / bat * fused_ips,
-            "fused_ok": bool(fused_ok),
-            "adaptive_ok": bool(adaptive_ok),
-        }
-    out["roofline"] = rows
-    out["roofline_fused_ok"] = bool(
-        all(r["fused_ok"] for r in rows.values()))
-    out["roofline_adaptive_ok"] = bool(
-        all(r["adaptive_ok"] for r in rows.values()))
-
-
 def main(n_requests: int = 768, items_per_req: int = 64,
          batch_items: int = 1024, quick: bool = False,
-         depths=(1, 2, 4), roofline_archs=("smollm-135m",
-                                           "dlrm-mlperf"),
-         roofline_full: bool = False) -> Dict:
+         depths=(1, 2, 4)) -> Dict:
     if quick:
         # Keep >= 16 batches per run: the depth sweep measures pipeline
         # overlap, which needs enough batches to amortize noise.
@@ -425,8 +264,6 @@ def main(n_requests: int = 768, items_per_req: int = 64,
     _throughput_phase(n_requests, items_per_req, batch_items, out,
                       depths=depths)
     _parity_phase(out)
-    _roofline_phase(out, quick=quick, archs=roofline_archs,
-                    full=roofline_full)
     # The shed_partition kernel's VMEM claim at the production config
     # (4 ways; the Trust DB itself stays in HBM behind an XLA gather).
     from repro.kernels.shed_partition import shed_partition_vmem_bytes
@@ -458,19 +295,6 @@ def main(n_requests: int = 768, items_per_req: int = 64,
           f"{'EXACT' if out['parity_ok'] else 'MISMATCH'}, no-drop "
           f"{'holds' if out['no_drop_ok'] else 'VIOLATED'} on both "
           f"paths")
-    print("roofline (heavyweight evaluators, "
-          f"{next(iter(out['roofline'].values()))['config']} configs):")
-    for arch, r in out["roofline"].items():
-        print(f"  {arch:>14}: AI {r['arithmetic_intensity']:7.1f} "
-              f"flop/B  eval_frac {r['eval_frac']:.2f}  host "
-              f"{r['host']['items_per_s']:8.0f}  fused(d="
-              f"{r['best_static_depth']}) "
-              f"{r['static'][str(r['best_static_depth'])]['items_per_s']:8.0f}"
-              f"  adaptive {r['adaptive']['items_per_s']:8.0f} items/s"
-              f"  [{'PASS' if r['fused_ok'] and r['adaptive_ok'] else 'FAIL'}]")
-    print(f"  roofline gates: fused "
-          f"{'PASS' if out['roofline_fused_ok'] else 'FAIL'}, adaptive "
-          f"{'PASS' if out['roofline_adaptive_ok'] else 'FAIL'}")
     return out
 
 
@@ -481,22 +305,13 @@ if __name__ == "__main__":
     ap.add_argument("--batch-items", type=int, default=1024)
     ap.add_argument("--depths", default="1,2,4",
                     help="comma-separated pipeline_depth sweep")
-    ap.add_argument("--roofline-archs", default="smollm-135m,dlrm-mlperf",
-                    help="comma-separated evaluator archs for the "
-                         "heavyweight roofline sweep")
-    ap.add_argument("--roofline-full", action="store_true",
-                    help="production (smoke=False) evaluator configs — "
-                         "real accelerators only")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--json", default="")
     args = ap.parse_args()
     rows = main(args.n_requests, args.items_per_req, args.batch_items,
                 quick=args.quick,
                 depths=tuple(int(d) for d in
-                             args.depths.split(",") if d),
-                roofline_archs=tuple(
-                    a for a in args.roofline_archs.split(",") if a),
-                roofline_full=args.roofline_full)
+                             args.depths.split(",") if d))
     if args.json:
         with open(args.json, "w") as f:
             json.dump(rows, f, indent=2)

@@ -92,43 +92,11 @@ def fanout_table(path: str = "BENCH_fanout.json") -> str:
     return "\n".join(rows)
 
 
-def fused_roofline_table(path: str = "BENCH_fused_drain.json") -> str:
-    """Measured heavyweight-evaluator roofline table from
-    ``benchmarks/bench_fused_drain.py`` (the dry-run HLO roofline above
-    is analytic; this one is wall-clock items/s through the serving
-    loop with the evaluator ON the fused drain hot path)."""
-    if not os.path.exists(path) or "roofline" not in json.load(
-            open(path)):
-        return f"(no roofline sweep in {path} — run `python " \
-               f"benchmarks/bench_fused_drain.py --json {path}` first)"
-    r = json.load(open(path))
-    rows = [
-        "| arch (config) | AI flop/B | eval frac | host items/s | "
-        "fused best (depth) | adaptive items/s | gates |",
-        "|---|---|---|---|---|---|---|",
-    ]
-    for arch, a in r["roofline"].items():
-        best_d = a["best_static_depth"]
-        rows.append(
-            f"| {arch} ({a['config']}) | "
-            f"{a['arithmetic_intensity']:.1f} | "
-            f"{a['eval_frac']:.2f}"
-            f"{' (dominated)' if a['eval_dominated'] else ''} | "
-            f"{a['host']['items_per_s']:,.0f} | "
-            f"{a['static'][str(best_d)]['items_per_s']:,.0f} "
-            f"(d={best_d}) | "
-            f"{a['adaptive']['items_per_s']:,.0f} | "
-            f"fused{' PASS' if a['fused_ok'] else ' FAIL'}, "
-            f"adaptive{' PASS' if a['adaptive_ok'] else ' FAIL'} |")
-    return "\n".join(rows)
-
-
 if __name__ == "__main__":
     p = argparse.ArgumentParser()
     p.add_argument("--mesh", default="single")
     p.add_argument("--which", default="both",
-                   choices=["dryrun", "roofline", "fanout",
-                            "fused-roofline", "both"])
+                   choices=["dryrun", "roofline", "fanout", "both"])
     a = p.parse_args()
     if a.which in ("dryrun", "both"):
         print("### Dry-run table (" + a.mesh + ")\n")
@@ -143,7 +111,3 @@ if __name__ == "__main__":
               "(32 straggler-injected shards)\n")
         print(fanout_table())
         print()
-    if a.which in ("fused-roofline", "both"):
-        print("### Heavyweight evaluators on the fused drain "
-              "(measured roofline)\n")
-        print(fused_roofline_table())

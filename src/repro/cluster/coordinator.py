@@ -87,6 +87,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import TrustIRConfig
 from repro.distribution.fault_tolerance import HedgedDispatch
 from repro.fanout import (FanoutSearcher, ReplicationPolicy,
@@ -469,49 +470,51 @@ class ClusterCoordinator:
         """Route by tenant, then admit on that replica. Returns the
         fleet-unique request id; a rejection completes immediately into
         ``self.completed``."""
-        rep = self.route(tenant)
-        if t_arrival is not None:
-            rep.advance_to(t_arrival)
-        self.tenants_seen.add(tenant)
-        n_before = len(rep.engine.completed)
-        arrival = rep.now()             # what the engine will stamp
-        self._now_hint = max(self._now_hint,
-                             t_arrival if t_arrival is not None
-                             else arrival)
-        if self.planner is not None:
-            self.planner.observe_arrival(
-                t_arrival if t_arrival is not None else arrival,
-                len(item_keys))
-        if self._feature_schema is None:
-            # Remember what a work batch looks like, so a prewarm pass
-            # can jit-compile the exact serving shapes later.
-            self._feature_schema = {
-                k: (tuple(np.asarray(v).shape[1:]),
-                    str(np.asarray(v).dtype))
-                for k, v in features.items()}
-        rid = rep.engine.enqueue(item_keys, buckets, features,
-                                 slo_s=slo_s, priority=priority,
-                                 tenant=tenant,
-                                 needs_kv_slot=needs_kv_slot)
-        self.stats.n_enqueued += 1
-        admitted = len(rep.engine.completed) == n_before
-        if admitted:
-            # Journal every admitted request until its response lands:
-            # crash recovery replays unanswered entries onto the ring's
-            # surviving owners (the no-drop invariant must not depend
-            # on a single replica's memory).
-            self._journal[rid] = _JournalEntry(
-                item_keys=item_keys, buckets=buckets, features=features,
-                arrival_s=arrival,
-                slo_s=(self.cfg.overload_deadline_s if slo_s is None
-                       else slo_s),
-                priority=priority, tenant=tenant,
-                needs_kv_slot=needs_kv_slot)
-        # A rejection completes immediately; only ADMITTED traffic
-        # earns hedge budget (rejected floods must not raise the cap).
-        if self.hedge is not None and admitted:
-            self.hedge.note_request()
-        self._collect()                 # surface immediate rejections
+        with obs.span("coord.enqueue", items=len(item_keys)) as sp:
+            rep = self.route(tenant)
+            if t_arrival is not None:
+                rep.advance_to(t_arrival)
+            self.tenants_seen.add(tenant)
+            n_before = len(rep.engine.completed)
+            arrival = rep.now()             # what the engine will stamp
+            self._now_hint = max(self._now_hint,
+                                 t_arrival if t_arrival is not None
+                                 else arrival)
+            if self.planner is not None:
+                self.planner.observe_arrival(
+                    t_arrival if t_arrival is not None else arrival,
+                    len(item_keys))
+            if self._feature_schema is None:
+                # Remember what a work batch looks like, so a prewarm pass
+                # can jit-compile the exact serving shapes later.
+                self._feature_schema = {
+                    k: (tuple(np.asarray(v).shape[1:]),
+                        str(np.asarray(v).dtype))
+                    for k, v in features.items()}
+            rid = rep.engine.enqueue(item_keys, buckets, features,
+                                     slo_s=slo_s, priority=priority,
+                                     tenant=tenant,
+                                     needs_kv_slot=needs_kv_slot)
+            self.stats.n_enqueued += 1
+            admitted = len(rep.engine.completed) == n_before
+            if admitted:
+                # Journal every admitted request until its response lands:
+                # crash recovery replays unanswered entries onto the ring's
+                # surviving owners (the no-drop invariant must not depend
+                # on a single replica's memory).
+                self._journal[rid] = _JournalEntry(
+                    item_keys=item_keys, buckets=buckets, features=features,
+                    arrival_s=arrival,
+                    slo_s=(self.cfg.overload_deadline_s if slo_s is None
+                           else slo_s),
+                    priority=priority, tenant=tenant,
+                    needs_kv_slot=needs_kv_slot)
+            # A rejection completes immediately; only ADMITTED traffic
+            # earns hedge budget (rejected floods must not raise the cap).
+            if self.hedge is not None and admitted:
+                self.hedge.note_request()
+            self._collect()             # surface immediate rejections
+            sp.set_metadata(rid=rid)
         return rid
 
     # -- retrieval front end -------------------------------------------------
@@ -1096,13 +1099,17 @@ class ClusterCoordinator:
             self._restart_hold = False
         return waves
 
-    _SCHED_INT_KEYS = ("n_submitted", "n_admitted", "n_rejected",
+    # Scheduler counters that add up across replicas (and across a
+    # replica's restarts).
+    _SCHED_SUM_KEYS = ("n_submitted", "n_admitted", "n_rejected",
                        "n_batches", "n_batched_items", "n_hedges",
-                       "n_executor_errors", "n_quarantined")
+                       "n_executor_errors", "n_quarantined",
+                       "n_eval_rows", "n_evaluated", "n_cached",
+                       "queue_wait_s", "n_queue_waits")
 
     @classmethod
     def _merge_sched_stats(cls, dst: Dict, src: Dict) -> None:
-        for k in cls._SCHED_INT_KEYS:
+        for k in cls._SCHED_SUM_KEYS:
             dst[k] = dst.get(k, 0) + src.get(k, 0)
         rbr = dst.setdefault("rejected_by_reason", {})
         for reason, c in src.get("rejected_by_reason", {}).items():
@@ -1121,16 +1128,19 @@ class ClusterCoordinator:
         """Collect every replica's fresh-evaluation taps: account
         fleet-wide duplicate evaluations, and (with gossip on) publish
         the deltas for this round's bounded broadcast."""
-        for rep in self.replicas:
-            for keys, vals in rep.take_cache_deltas():
-                self.stats.n_eval_items += len(keys)
-                for k in keys.tolist():
-                    c = self._eval_counts.get(k, 0)
-                    if c:
-                        self.stats.n_duplicate_evals += 1
-                    self._eval_counts[k] = c + 1
-                if self.gossip is not None:
-                    self.gossip.publish(rep.replica_id, keys, vals)
+        n0 = self.stats.n_eval_items
+        with obs.span("coord.harvest") as sp:
+            for rep in self.replicas:
+                for keys, vals in rep.take_cache_deltas():
+                    self.stats.n_eval_items += len(keys)
+                    for k in keys.tolist():
+                        c = self._eval_counts.get(k, 0)
+                        if c:
+                            self.stats.n_duplicate_evals += 1
+                        self._eval_counts[k] = c + 1
+                    if self.gossip is not None:
+                        self.gossip.publish(rep.replica_id, keys, vals)
+            sp.set_metadata(keys=self.stats.n_eval_items - n0)
 
     # -- steal ---------------------------------------------------------------
     def _steal_rebalance(self,
@@ -1286,69 +1296,75 @@ class ClusterCoordinator:
         produced: List[Response] = []
         rounds = 0
         while max_rounds is None or rounds < max_rounds:
-            # Fold completed in-flight batches back BEFORE deciding
-            # anything: steal/hedge/autoscale read fresh stats.
-            for rep in self.replicas:
-                rep.engine.poll()
-            # ONE load index per round (O(n) heapify over the polled
-            # queue depths): the steal loop updates it per steal and
-            # the autoscale victim pick reads it, instead of each scan
-            # re-sorting the fleet.
-            heap = ReplicaLoadHeap({r.replica_id: r.queued_items
-                                    for r in self.replicas})
-            self._steal_rebalance(heap)
-            self._hedge_scan()
-            self._fanout_maintenance()
-            any_batch = False
-            for rep in list(self.replicas):
-                # n_submitted counts rescued batches too: a batch whose
-                # dispatch raised still consumed queue work (and was
-                # prior-answered), so the round made progress.
-                before = rep.scheduler.executor.n_submitted
-                rep.engine.drain(max_batches=1, flush=False)
-                any_batch |= \
-                    rep.scheduler.executor.n_submitted > before
-                if rep.replica_id in heap:
-                    heap.update(rep.replica_id, rep.queued_items)
-                if rep.replica_id in self._prewarm_watch \
-                        and rep.scheduler.stats.n_batches > 0:
-                    # First real batch after a pre-warmed join: any NEW
-                    # warmup exclusion means a jit shape the prewarm
-                    # missed — the join was cold after all.
-                    if rep.warmup_exclusions() > \
-                            self._prewarm_watch.pop(rep.replica_id):
-                        self.stats.n_cold_joins += 1
-            # Gossip: harvest this round's cache fills (duplicate-eval
-            # accounting either way), then broadcast the freshest
-            # deltas to siblings under the per-round budget.
-            self._harvest_cache_deltas()
-            if self.gossip is not None:
-                self.gossip.flush(self.replicas)
-            produced.extend(self._collect())
-            rounds += 1
-            self.stats.n_drain_rounds += 1
-            if self.autoscaler is not None and \
-                    self.stats.n_drain_rounds \
-                    % max(self.cluster_cfg.autoscale_every, 1) == 0:
-                self.last_snapshot = self.autoscaler.update(
-                    self.replicas, self.tenants_seen)
-                fp = None
-                if self.planner is not None:
-                    fp = self.planner.forecast_pressure(
-                        self._now_hint,
-                        rate_items_per_s=(
-                            self.last_snapshot.rate_items_per_s))
-                self._autoscale_membership(heap, forecast_pressure=fp)
-            if not any_batch:
-                # Queues are empty; land whatever is still in flight
-                # (their fold-backs may gossip) and finish.
+            with obs.span("coord.round"):
+                # Fold completed in-flight batches back BEFORE deciding
+                # anything: steal/hedge/autoscale read fresh stats.
                 for rep in self.replicas:
-                    rep.engine.flush()
+                    rep.engine.poll()
+                # ONE load index per round (O(n) heapify over the polled
+                # queue depths): the steal loop updates it per steal and
+                # the autoscale victim pick reads it, instead of each scan
+                # re-sorting the fleet.
+                heap = ReplicaLoadHeap({r.replica_id: r.queued_items
+                                        for r in self.replicas})
+                with obs.span("coord.steal"):
+                    self._steal_rebalance(heap)
+                with obs.span("coord.hedge"):
+                    self._hedge_scan()
+                with obs.span("coord.fanout"):
+                    self._fanout_maintenance()
+                any_batch = False
+                for rep in list(self.replicas):
+                    # n_submitted counts rescued batches too: a batch whose
+                    # dispatch raised still consumed queue work (and was
+                    # prior-answered), so the round made progress.
+                    before = rep.scheduler.executor.n_submitted
+                    rep.engine.drain(max_batches=1, flush=False)
+                    any_batch |= \
+                        rep.scheduler.executor.n_submitted > before
+                    if rep.replica_id in heap:
+                        heap.update(rep.replica_id, rep.queued_items)
+                    if rep.replica_id in self._prewarm_watch \
+                            and rep.scheduler.stats.n_batches > 0:
+                        # First real batch after a pre-warmed join: any NEW
+                        # warmup exclusion means a jit shape the prewarm
+                        # missed — the join was cold after all.
+                        if rep.warmup_exclusions() > \
+                                self._prewarm_watch.pop(rep.replica_id):
+                            self.stats.n_cold_joins += 1
+                # Gossip: harvest this round's cache fills (duplicate-eval
+                # accounting either way), then broadcast the freshest
+                # deltas to siblings under the per-round budget.
                 self._harvest_cache_deltas()
                 if self.gossip is not None:
-                    self.gossip.flush(self.replicas)
+                    with obs.span("coord.gossip"):
+                        self.gossip.flush(self.replicas)
                 produced.extend(self._collect())
-                break
+                rounds += 1
+                self.stats.n_drain_rounds += 1
+                if self.autoscaler is not None and \
+                        self.stats.n_drain_rounds \
+                        % max(self.cluster_cfg.autoscale_every, 1) == 0:
+                    self.last_snapshot = self.autoscaler.update(
+                        self.replicas, self.tenants_seen)
+                    fp = None
+                    if self.planner is not None:
+                        fp = self.planner.forecast_pressure(
+                            self._now_hint,
+                            rate_items_per_s=(
+                                self.last_snapshot.rate_items_per_s))
+                    self._autoscale_membership(heap, forecast_pressure=fp)
+                if not any_batch:
+                    # Queues are empty; land whatever is still in flight
+                    # (their fold-backs may gossip) and finish.
+                    for rep in self.replicas:
+                        rep.engine.flush()
+                    self._harvest_cache_deltas()
+                    if self.gossip is not None:
+                        with obs.span("coord.gossip"):
+                            self.gossip.flush(self.replicas)
+                    produced.extend(self._collect())
+                    break
         return produced
 
     def _collect(self) -> List[Response]:
@@ -1361,33 +1377,34 @@ class ClusterCoordinator:
         so lower latency IS earlier completion — not by replica scan
         order (the hedge exists precisely because the primary is slow,
         and scan order would keep the loser)."""
-        window: List[Response] = []
-        for rep in self.replicas:
-            comp = rep.engine.completed
-            while rep.n_collected < len(comp):
-                window.append(comp[rep.n_collected])
-                rep.n_collected += 1
-        by_rid: Dict[int, Response] = {}
-        order: List[int] = []
-        for resp in window:
-            rid = resp.request_id
-            if rid in self._responded:      # twin answered last window
-                self.stats.n_twin_drops += 1
-                continue
-            if rid in by_rid:               # both twins in this window
-                self.stats.n_twin_drops += 1
-                if resp.latency_s < by_rid[rid].latency_s:
-                    by_rid[rid] = resp
-                continue
-            by_rid[rid] = resp
-            order.append(rid)
-        fresh = [by_rid[rid] for rid in order]
-        for resp in fresh:
-            self._responded.add(resp.request_id)
-            self.completed.append(resp)
-            self._journal.pop(resp.request_id, None)    # answered
-            if resp.admitted:
-                self.capacity.observe_queue(resp.queue_delay_s)
+        with obs.span("coord.collect"):
+            window: List[Response] = []
+            for rep in self.replicas:
+                comp = rep.engine.completed
+                while rep.n_collected < len(comp):
+                    window.append(comp[rep.n_collected])
+                    rep.n_collected += 1
+            by_rid: Dict[int, Response] = {}
+            order: List[int] = []
+            for resp in window:
+                rid = resp.request_id
+                if rid in self._responded:      # twin answered last window
+                    self.stats.n_twin_drops += 1
+                    continue
+                if rid in by_rid:               # both twins in this window
+                    self.stats.n_twin_drops += 1
+                    if resp.latency_s < by_rid[rid].latency_s:
+                        by_rid[rid] = resp
+                    continue
+                by_rid[rid] = resp
+                order.append(rid)
+            fresh = [by_rid[rid] for rid in order]
+            for resp in fresh:
+                self._responded.add(resp.request_id)
+                self.completed.append(resp)
+                self._journal.pop(resp.request_id, None)    # answered
+                if resp.admitted:
+                    self.capacity.observe_queue(resp.queue_delay_s)
         return fresh
 
     # -- observability -------------------------------------------------------
@@ -1397,7 +1414,7 @@ class ClusterCoordinator:
     def scheduler_stats(self) -> Dict:
         """Fleet aggregate in the single-engine stats shape (drivers and
         reports consume both interchangeably), plus cluster extras."""
-        agg: Dict = {k: 0 for k in self._SCHED_INT_KEYS}
+        agg: Dict = {k: 0 for k in self._SCHED_SUM_KEYS}
         agg["rejected_by_reason"] = {}
         per_replica: Dict[str, Dict] = {}
         live = {rep.replica_id: rep.scheduler.stats.as_dict()

@@ -40,7 +40,8 @@ class ProcessAll(LoadShedder):
                           regime=classify(n, ucap, uthr),
                           response_time_s=rt,
                           deadline_eff_s=self.cfg.deadline_s,
-                          n_evaluated=n, n_cached=0, n_prior=0, uload=n)
+                          n_evaluated=n, n_cached=0, n_prior=0, uload=n,
+                          n_eval_rows=self._chunk_rows(n))
 
 
 class RLSEDA(LoadShedder):
@@ -70,4 +71,5 @@ class RLSEDA(LoadShedder):
                           response_time_s=rt,
                           deadline_eff_s=self.cfg.overload_deadline_s,
                           n_evaluated=int(len(keep)), n_cached=0,
-                          n_prior=0, uload=n)
+                          n_prior=0, uload=n,
+                          n_eval_rows=self._chunk_rows(len(keep)))

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import TrustIRConfig
 from repro.core import average_trust as AT
 from repro.core import trust_cache as TC
@@ -90,11 +91,11 @@ class StagedBatch:
     """
 
     __slots__ = ("item_keys", "keys_j", "buckets_j", "valid_j",
-                 "feats_j", "n", "n_total", "t_start", "wall_start")
+                 "feats_j", "n", "n_total", "t_start", "wall_start", "seq")
 
     def __init__(self, item_keys, keys_j, buckets_j, valid_j, feats_j,
                  n: int, n_total: int, t_start: float,
-                 wall_start: float):
+                 wall_start: float, seq: int = -1):
         self.item_keys = item_keys
         self.keys_j = keys_j
         self.buckets_j = buckets_j
@@ -104,6 +105,7 @@ class StagedBatch:
         self.n_total = n_total
         self.t_start = t_start
         self.wall_start = wall_start
+        self.seq = seq
 
 
 class PendingShed:
@@ -111,14 +113,16 @@ class PendingShed:
 
     ``trust``/``tier`` stay device-resident (possibly still computing —
     JAX async dispatch) until :meth:`result` materializes them, charges
-    the clock/monitor, and builds the :class:`ShedResult`.
+    the clock/monitor, and builds the :class:`ShedResult`. ``seq`` is
+    the dispatcher's batch sequence number, carried into the spans of
+    the batch's sync and fold-back.
     """
 
     def __init__(self, shedder: "FusedLoadShedder", trust, tier,
                  n_evald, *, t_start: float, wall_start: float,
-                 n: int, regime, deadline_eff: float,
+                 n: int, regime, deadline_eff: float, max_evals: int,
                  skip_observe: bool = False,
-                 item_keys: Optional[np.ndarray] = None):
+                 item_keys: Optional[np.ndarray] = None, seq: int = -1):
         self._shedder = shedder
         self._trust = trust
         self._tier = tier
@@ -129,12 +133,20 @@ class PendingShed:
         self._n = n
         self._regime = regime
         self._deadline_eff = deadline_eff
+        self._max_evals = max_evals
         self._skip_observe = skip_observe
+        self.seq = seq
         self._result: Optional[ShedResult] = None
         # Wall time at which the step was FIRST observed complete
         # (stamped by is_ready): the honest end of the throughput
         # window when finalize happens long after completion.
         self._wall_ready: Optional[float] = None
+
+    @property
+    def new_shape(self) -> bool:
+        """True when the step's work shape was seen for the first time
+        (the dispatch compiled it)."""
+        return self._skip_observe
 
     def result(self) -> ShedResult:
         if self._result is None:
@@ -236,38 +248,49 @@ class FusedLoadShedder(LoadShedder):
             # whole (small, replicated) batch.
             probe = shard_map(probe, mesh=self._mesh, in_specs=P(),
                               out_specs=P())
-        tier, cval, rank = probe(keys, valid, cache["keys"],
-                                 cache["values"], u_capacity, u_threshold,
-                                 budget_total)
-        # Safety on a too-small max_evals: overflow evals fall back to
-        # the prior tier (no-drop) instead of silently scoring 0. The
-        # default max_evals = batch capacity can never overflow.
-        tier = jnp.where((rank >= max_evals) & (tier == TIER_EVAL),
-                         TIER_PRIOR, tier)
-        idx, eval_valid = eval_indices_from_rank(rank, max_evals)
-        gidx = jnp.minimum(idx, n - 1)              # clamp pad slots
-        sub = jax.tree.map(lambda a: a[gidx], features)
-        scores = self._apply(eval_params, sub)      # (max_evals,)
-        scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
-            jnp.where(eval_valid, scores.astype(jnp.float32), 0.0),
-            mode="drop")
-        prior_vals = AT.query(prior, buckets)
-        trust = combine_trust(tier, scattered, cval, prior_vals)
-        evald = tier == TIER_EVAL
-        new_cache = TC.insert(cache, keys, trust, evald)
-        new_prior = AT.update(prior, buckets, trust, evald,
-                              ewma=self.cfg.prior_ewma)
+        # Each stage under its own name scope, so the device trace
+        # names the operations of a stage after it.
+        with jax.named_scope("probe"):
+            tier, cval, rank = probe(keys, valid, cache["keys"],
+                                     cache["values"], u_capacity,
+                                     u_threshold, budget_total)
+        with jax.named_scope("gather"):
+            # Safety on a too-small max_evals: overflow evals fall back
+            # to the prior tier (no-drop) instead of silently scoring 0.
+            # The default max_evals = batch capacity can never overflow.
+            tier = jnp.where((rank >= max_evals) & (tier == TIER_EVAL),
+                             TIER_PRIOR, tier)
+            idx, eval_valid = eval_indices_from_rank(rank, max_evals)
+            gidx = jnp.minimum(idx, n - 1)          # clamp pad slots
+            sub = jax.tree.map(lambda a: a[gidx], features)
+        with jax.named_scope("evaluate"):
+            scores = self._apply(eval_params, sub)  # (max_evals,)
+        with jax.named_scope("scatter_combine"):
+            scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
+                jnp.where(eval_valid, scores.astype(jnp.float32), 0.0),
+                mode="drop")
+            prior_vals = AT.query(prior, buckets)
+            trust = combine_trust(tier, scattered, cval, prior_vals)
+            evald = tier == TIER_EVAL
+        with jax.named_scope("db_insert"):
+            new_cache = TC.insert(cache, keys, trust, evald)
+        with jax.named_scope("prior_update"):
+            new_prior = AT.update(prior, buckets, trust, evald,
+                                  ewma=self.cfg.prior_ewma)
         return (trust, tier, jnp.sum(evald.astype(jnp.int32)),
                 new_cache, new_prior)
 
     # -- stage / dispatch / finish --------------------------------------------
     def stage(self, item_keys: np.ndarray, buckets: np.ndarray,
-              features, n_valid: Optional[int] = None) -> StagedBatch:
+              features, n_valid: Optional[int] = None,
+              seq: int = -1) -> StagedBatch:
         """Front half of the fused step: ONE host->device transfer per
         batch (the host path re-gathers from the feature pytree once
         per chunk). The copies are enqueued asynchronously, so under a
         depth-k executor the transfer of batch N+2 runs behind batch
-        N's in-flight compute — the transfer half of the pipeline."""
+        N's in-flight compute — the transfer half of the pipeline.
+        ``seq`` (the executor's batch sequence number) travels with the
+        batch to its sync and fold-back spans."""
         t_start = self._now()
         wall_start = time.monotonic()
         n_total = len(item_keys)
@@ -288,7 +311,7 @@ class FusedLoadShedder(LoadShedder):
             valid_j=jnp.asarray(valid),
             feats_j=feats_j,
             n=n, n_total=n_total, t_start=t_start,
-            wall_start=wall_start)
+            wall_start=wall_start, seq=seq)
 
     def dispatch_staged(self, staged: StagedBatch) -> PendingShed:
         """Back half: launch the jitted shedding step on staged
@@ -322,8 +345,10 @@ class FusedLoadShedder(LoadShedder):
                               wall_start=staged.wall_start,
                               n=n, regime=regime,
                               deadline_eff=deadline_eff,
+                              max_evals=max_evals,
                               skip_observe=not warm,
-                              item_keys=staged.item_keys)
+                              item_keys=staged.item_keys,
+                              seq=staged.seq)
         if self.sim_clock is not None:
             pending.result()
         return pending
@@ -339,9 +364,10 @@ class FusedLoadShedder(LoadShedder):
     def _finish(self, p: PendingShed) -> ShedResult:
         t_entry = time.monotonic()
         ready_at_entry = p.is_ready()   # stamps _wall_ready if so
-        trust = np.asarray(p._trust)                # sync point
-        tier = np.asarray(p._tier)
-        n_evald = int(p._n_evald)
+        with obs.span("exec.sync", batch=p.seq, ready=int(ready_at_entry)):
+            trust = np.asarray(p._trust)            # sync point
+            tier = np.asarray(p._tier)
+            n_evald = int(p._n_evald)
         wall_end = time.monotonic()
         if self.sim_clock is not None:
             self.sim_clock.charge_probe()
@@ -380,7 +406,7 @@ class FusedLoadShedder(LoadShedder):
             n_evaluated=n_evald,
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=p._n)
+            uload=p._n, n_eval_rows=p._max_evals)
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None and p._item_keys is not None:

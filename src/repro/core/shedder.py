@@ -205,6 +205,10 @@ class ShedResult:
     n_cached: int
     n_prior: int
     uload: int
+    # Rows the evaluator computed for the batch, padding included: the
+    # fused step's ``max_evals``, or the host chunk loop's chunks times
+    # the chunk size. 0 for a request's share of a batch.
+    n_eval_rows: int = 0
 
     @property
     def no_item_dropped(self) -> bool:
@@ -310,6 +314,11 @@ class LoadShedder:
             out[s:s + len(chunk_idx)] = scores[:len(chunk_idx)]
         return out
 
+    def _chunk_rows(self, n: int) -> int:
+        """Rows ``_eval`` computes for ``n`` items: whole chunks."""
+        cs = self.cfg.chunk_size
+        return -(-n // cs) * cs
+
     # -- the algorithm (§5.1 Load_Shedder) ----------------------------------
     def process(self, item_keys: np.ndarray, buckets: np.ndarray,
                 features, n_valid: Optional[int] = None) -> ShedResult:
@@ -355,9 +364,11 @@ class LoadShedder:
         nq_eval = nq[~hit[:n_normal]]
         trust[nq_hit] = cached_vals[nq_hit]
         tier[nq_hit] = TIER_CACHED
+        n_eval_rows = 0
         if len(nq_eval):
             trust[nq_eval] = self._eval(features, nq_eval)
             tier[nq_eval] = TIER_EVAL
+            n_eval_rows += self._chunk_rows(len(nq_eval))
 
         # ---- Drop Queue (§5.3 / §5.4) ----
         if n > n_normal:
@@ -380,6 +391,7 @@ class LoadShedder:
                 trust[take] = self._eval(features, take)
                 tier[take] = TIER_EVAL
                 done += len(take)
+                n_eval_rows += self._chunk_rows(len(take))
             # rest: average trustworthiness (prior) — host-side numpy
             # lookup (ragged sizes would retrace a jit per request)
             rest = dq_eval_cand[done:]
@@ -405,7 +417,7 @@ class LoadShedder:
             n_evaluated=int(evald.sum()),
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=n)
+            uload=n, n_eval_rows=n_eval_rows)
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None:

@@ -263,6 +263,7 @@ def shed_partition(keys: jnp.ndarray, valid: jnp.ndarray,
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="shed_partition",
         **kwargs,
     )(params, keys2, valid2, cand_k, cand_v)
     return (tier.reshape(-1)[:n], cval.reshape(-1)[:n],

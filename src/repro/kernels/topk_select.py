@@ -162,6 +162,7 @@ def topk_select(scores: jnp.ndarray, k: int, *,
             jax.ShapeDtypeStruct((n_blocks * crows, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_select",
         **kwargs,
     )(scores_p.reshape(rows, LANES))
 

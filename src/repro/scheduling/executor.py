@@ -45,6 +45,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Callable, Deque, List, Optional, Tuple
 
+from repro import obs
+
 
 class DrainExecutor:
     """Depth-k micro-batch execution window over a shedder.
@@ -67,6 +69,9 @@ class DrainExecutor:
         self._rescue = rescue
         self._on_error = on_error
         self.depth = max(1, int(depth))
+        # (batch, handle) in dispatch order. Each handle carries its
+        # batch's sequence number (``seq``), which joins the batch's
+        # stage, dispatch, sync and fold-back spans across loop turns.
         self._window: Deque[Tuple[Any, Any]] = deque()
         self.n_dispatched = 0
         self.n_completed = 0
@@ -123,8 +128,9 @@ class DrainExecutor:
             # pipelined throughput observations honest (see
             # FusedLoadShedder._finish).
             self._is_ready(self._window[0][1])
+        seq = self.n_submitted
         try:
-            handle = self._dispatch(batch)
+            handle = self._dispatch(batch, seq)
         except Exception as exc:                  # noqa: BLE001
             return self._do_rescue(batch, exc)
         self._window.append((batch, handle))
@@ -134,30 +140,39 @@ class DrainExecutor:
             out.extend(self._finalize_oldest())
         return out
 
-    def _dispatch(self, batch):
+    def _dispatch(self, batch, seq: int):
         sh = self.shedder
-        if getattr(sh, "supports_async", False):
-            if hasattr(sh, "stage"):
-                # Transfer stage first, step dispatch second: the
-                # host->device copies enqueue behind the in-flight
-                # steps of older batches (JAX async dispatch), so at
-                # depth >= 2 batch N+2's features stream to the device
-                # while N computes and N+1 waits its turn.
-                return sh.dispatch_staged(
-                    sh.stage(batch.item_keys, batch.buckets,
-                             batch.features, n_valid=batch.n_valid))
-            return sh.process_async(batch.item_keys, batch.buckets,
-                                    batch.features,
-                                    n_valid=batch.n_valid)
-        return _EagerHandle(sh.process(batch.item_keys, batch.buckets,
-                                       batch.features,
-                                       n_valid=batch.n_valid))
+        rows = len(batch.item_keys)
+        if getattr(sh, "supports_async", False) and hasattr(sh, "stage"):
+            # Transfer stage first, step dispatch second: the
+            # host->device copies enqueue behind the in-flight steps of
+            # older batches (JAX async dispatch), so at depth >= 2 batch
+            # N+2's features stream to the device while N computes and
+            # N+1 waits its turn.
+            with obs.span("exec.stage", batch=seq, rows=rows):
+                staged = sh.stage(batch.item_keys, batch.buckets,
+                                  batch.features, n_valid=batch.n_valid,
+                                  seq=seq)
+            with obs.span("exec.dispatch", batch=seq, rows=rows) as sp:
+                handle = sh.dispatch_staged(staged)
+                sp.set_metadata(new_shape=int(handle.new_shape))
+            return handle
+        with obs.span("exec.dispatch", batch=seq, rows=rows):
+            if getattr(sh, "supports_async", False):
+                return sh.process_async(batch.item_keys, batch.buckets,
+                                        batch.features,
+                                        n_valid=batch.n_valid)
+            return _EagerHandle(sh.process(batch.item_keys,
+                                           batch.buckets, batch.features,
+                                           n_valid=batch.n_valid), seq)
 
     def _finalize_oldest(self) -> List:
         batch, handle = self._window.popleft()
         try:
-            shed = handle.result()
-            out = self._finalize(batch, shed)
+            with obs.span("exec.foldback",
+                          batch=getattr(handle, "seq", -1)):
+                shed = handle.result()
+                out = self._finalize(batch, shed)
         except Exception as exc:                  # noqa: BLE001
             return self._do_rescue(batch, exc)
         self.n_completed += 1
@@ -181,8 +196,9 @@ class DrainExecutor:
         coordinator calls this before steal/hedge/autoscale scans so
         fleet decisions read stats as fresh as the hardware allows."""
         out: List = []
-        while self._window and self._is_ready(self._window[0][1]):
-            out.extend(self._finalize_oldest())
+        with obs.span("exec.poll"):
+            while self._window and self._is_ready(self._window[0][1]):
+                out.extend(self._finalize_oldest())
         return out
 
     @staticmethod
@@ -204,10 +220,11 @@ class _EagerHandle:
     """Adapter giving synchronous shedders the async-handle interface
     (the result exists the moment the handle does)."""
 
-    __slots__ = ("_result",)
+    __slots__ = ("_result", "seq")
 
-    def __init__(self, result):
+    def __init__(self, result, seq: int = -1):
         self._result = result
+        self.seq = seq
 
     def result(self):
         return self._result

@@ -33,6 +33,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import TrustIRConfig
 from repro.core.regimes import Regime, classify
 from repro.core.shedder import (LoadShedder, ShedResult, TIER_CACHED,
@@ -106,6 +107,15 @@ class SchedulerStats:
     n_hedges: int = 0
     n_executor_errors: int = 0      # batches rescued from the prior
     n_quarantined: int = 0          # requests blocked by an open breaker
+    # Per batch, from its ShedResult: rows the evaluator computed
+    # (padding included), candidates evaluated, Trust-DB hits.
+    n_eval_rows: int = 0
+    n_evaluated: int = 0
+    n_cached: int = 0
+    # Summed queue_delay_s of the responses batches produced, and their
+    # number (rescued batches included).
+    queue_wait_s: float = 0.0
+    n_queue_waits: int = 0
 
     def as_dict(self) -> Dict:
         return {"n_submitted": self.n_submitted,
@@ -117,6 +127,11 @@ class SchedulerStats:
                 "n_hedges": self.n_hedges,
                 "n_executor_errors": self.n_executor_errors,
                 "n_quarantined": self.n_quarantined,
+                "n_eval_rows": self.n_eval_rows,
+                "n_evaluated": self.n_evaluated,
+                "n_cached": self.n_cached,
+                "queue_wait_s": self.queue_wait_s,
+                "n_queue_waits": self.n_queue_waits,
                 "mean_batch_fill": (self.n_batched_items
                                     / max(self.n_batches, 1))}
 
@@ -350,7 +365,10 @@ class Scheduler:
         while max_batches is None or n_done < max_batches:
             if self.hedge is not None:
                 self._hedge_scan()
-            batch = self.batcher.form(self.bank, kv_free=kv_budget)
+            with obs.span("sched.form") as sp:
+                batch = self.batcher.form(self.bank, kv_free=kv_budget)
+                if sp and batch is not None:
+                    sp.set_metadata(n_valid=batch.n_valid)
             if batch is None:
                 break
             if kv_budget is not None:
@@ -413,7 +431,12 @@ class Scheduler:
                 hedged=qreq.hedged))
             if qreq.hedged and self.hedge is not None:
                 self._answered.add(rid)
+        self._count_queue_waits(responses)
         return responses
+
+    def _count_queue_waits(self, responses: List[Response]) -> None:
+        self.stats.queue_wait_s += sum(r.queue_delay_s for r in responses)
+        self.stats.n_queue_waits += len(responses)
 
     def _split_responses(self, batch: MicroBatch,
                          shed: ShedResult) -> List[Response]:
@@ -422,6 +445,9 @@ class Scheduler:
         batch_start = end - shed.response_time_s
         self.stats.n_batches += 1
         self.stats.n_batched_items += nv
+        self.stats.n_eval_rows += shed.n_eval_rows
+        self.stats.n_evaluated += shed.n_evaluated
+        self.stats.n_cached += shed.n_cached
         if self.quarantine is not None and self.quarantine.any_tracked:
             # Clean completion: decay strikes / close half-open probes
             # for every signature this batch carried.
@@ -467,4 +493,5 @@ class Scheduler:
                 # self.hedge is None), the ClusterCoordinator owns the
                 # fleet-wide dedup instead.
                 self._answered.add(rid)
+        self._count_queue_waits(responses)
         return responses
