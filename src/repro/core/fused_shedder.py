@@ -10,9 +10,12 @@ shedding decision into ONE device dispatch per micro-batch:
     shed_partition (XLA gather of each key's Trust-DB set, then
                     Pallas: way compare + tier scan, SMEM
                     write-cursor emits compacted eval ranks)
-      -> eval_indices_from_rank   O(N) scatter, no argsort
-      -> static-shape gather      features picked once, on device
-      -> evaluator forward        one batched call, no chunk loop
+      -> eval_indices_from_rank   O(N) scatter, no argsort: the
+                                  evaluated rows are the prefix
+      -> evaluator loop           ceil(n_evald / slice) slices, each
+                                  gathers its rows' features and runs
+                                  one forward; rows past the prefix
+                                  are never computed
       -> scatter + combine        trust per tier
       -> TC.insert / AT.update    cache + prior fold-back, donated
                                   buffers update in place
@@ -68,6 +71,10 @@ from repro.core.shedder import (LoadShedder, ShedResult, SimClock,
                                 combine_trust, eval_indices_from_rank)
 
 
+# Most slices the evaluator loop takes for a full micro-batch.
+MAX_SLICES = 16
+
+
 def _mesh_of(params) -> Optional[Mesh]:
     """The multi-device mesh a sharded evaluator's parameters live on,
     or None when they sit on one device."""
@@ -120,7 +127,7 @@ class PendingShed:
 
     def __init__(self, shedder: "FusedLoadShedder", trust, tier,
                  n_evald, *, t_start: float, wall_start: float,
-                 n: int, regime, deadline_eff: float, max_evals: int,
+                 n: int, regime, deadline_eff: float,
                  skip_observe: bool = False,
                  item_keys: Optional[np.ndarray] = None, seq: int = -1):
         self._shedder = shedder
@@ -133,7 +140,6 @@ class PendingShed:
         self._n = n
         self._regime = regime
         self._deadline_eff = deadline_eff
-        self._max_evals = max_evals
         self._skip_observe = skip_observe
         self.seq = seq
         self._result: Optional[ShedResult] = None
@@ -169,9 +175,9 @@ class PendingShed:
 class FusedLoadShedder(LoadShedder):
     """Drop-in ``LoadShedder`` whose ``process`` runs the fused device
     step. ``evaluate_batch`` must be jax-traceable: features pytree
-    (leading dim ``max_evals``) -> (max_evals,) scores. When it carries
-    ``apply``/``params`` (a ``serving.evaluators.Evaluator``) the step
-    calls ``apply`` with the params as a step argument. The host
+    (leading dim: one slice's rows) -> one score per row. When it
+    carries ``apply``/``params`` (a ``serving.evaluators.Evaluator``) the
+    step calls ``apply`` with the params as a step argument. The host
     executor's ``evaluate_chunk`` protocol is satisfied by the same
     callable whenever it is traceable (every ``serving.evaluators``
     backend is), so baseline drivers can still call the inherited
@@ -221,6 +227,15 @@ class FusedLoadShedder(LoadShedder):
             rep = NamedSharding(self._mesh, P())
             self.cache = jax.device_put(self.cache, rep)
             self.prior = jax.device_put(self.prior, rep)
+        # Rows of one evaluator slice: chunk_size, widened by whole
+        # chunks until a full micro-batch (u_capacity + u_threshold, what
+        # the scheduler packs) takes at most MAX_SLICES slices. Every
+        # slice launches the evaluator's operations once, whatever its
+        # rows: the bound keeps a wide batch of a cheap evaluator from
+        # paying many launches for few rows each.
+        cs = cfg.chunk_size
+        self._slice = cs * -(-(cfg.u_capacity + cfg.u_threshold)
+                             // (cs * MAX_SLICES))
         self._step = jax.jit(self._step_impl,
                              static_argnames=("max_evals",),
                              donate_argnums=(0, 1))
@@ -230,6 +245,10 @@ class FusedLoadShedder(LoadShedder):
         self._last_obs_wall = 0.0
 
     # -- the fused device step ----------------------------------------------
+    def _slice_cover(self, n_rows: int) -> int:
+        """Rows of the whole slices that hold ``n_rows`` rows."""
+        return -(-n_rows // self._slice) * self._slice
+
     def _step_impl(self, cache, prior, eval_params, keys, buckets, valid,
                    features, u_capacity, u_threshold, budget_total, *,
                    max_evals: int):
@@ -261,13 +280,28 @@ class FusedLoadShedder(LoadShedder):
             tier = jnp.where((rank >= max_evals) & (tier == TIER_EVAL),
                              TIER_PRIOR, tier)
             idx, eval_valid = eval_indices_from_rank(rank, max_evals)
+            # Evaluated rows are the prefix idx[:n_evald]; pad slots
+            # (value n) fill idx up to whole slices.
+            s = self._slice
+            rows = self._slice_cover(max_evals)
+            idx = jnp.pad(idx, (0, rows - max_evals), constant_values=n)
+            eval_valid = jnp.pad(eval_valid, (0, rows - max_evals))
             gidx = jnp.minimum(idx, n - 1)          # clamp pad slots
-            sub = jax.tree.map(lambda a: a[gidx], features)
+            n_slices = (jnp.sum(eval_valid.astype(jnp.int32)) + s - 1) // s
         with jax.named_scope("evaluate"):
-            scores = self._apply(eval_params, sub)  # (max_evals,)
+            # Only the slices that hold evaluated rows run: the step's
+            # evaluator cost follows the rows served, not n_total.
+            def one_slice(i, scores):
+                sl = jax.lax.dynamic_slice_in_dim(gidx, i * s, s)
+                sub = jax.tree.map(lambda a: a[sl], features)
+                out = self._apply(eval_params, sub).astype(jnp.float32)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    scores, out, i * s, 0)
+            scores = jax.lax.fori_loop(0, n_slices, one_slice,
+                                       jnp.zeros((rows,), jnp.float32))
         with jax.named_scope("scatter_combine"):
             scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
-                jnp.where(eval_valid, scores.astype(jnp.float32), 0.0),
+                jnp.where(eval_valid, scores, 0.0),
                 mode="drop")
             prior_vals = AT.query(prior, buckets)
             trust = combine_trust(tier, scattered, cval, prior_vals)
@@ -345,7 +379,6 @@ class FusedLoadShedder(LoadShedder):
                               wall_start=staged.wall_start,
                               n=n, regime=regime,
                               deadline_eff=deadline_eff,
-                              max_evals=max_evals,
                               skip_observe=not warm,
                               item_keys=staged.item_keys,
                               seq=staged.seq)
@@ -406,7 +439,7 @@ class FusedLoadShedder(LoadShedder):
             n_evaluated=n_evald,
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=p._n, n_eval_rows=p._max_evals)
+            uload=p._n, n_eval_rows=self._slice_cover(n_evald))
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None and p._item_keys is not None:

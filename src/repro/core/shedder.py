@@ -206,8 +206,9 @@ class ShedResult:
     n_prior: int
     uload: int
     # Rows the evaluator computed for the batch, padding included: the
-    # fused step's ``max_evals``, or the host chunk loop's chunks times
-    # the chunk size. 0 for a request's share of a batch.
+    # fused step's slices times the slice size, or the host chunk
+    # loop's chunks times the chunk size. 0 for a request's share of a
+    # batch.
     n_eval_rows: int = 0
 
     @property

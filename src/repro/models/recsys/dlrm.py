@@ -7,6 +7,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import RecsysConfig
 from repro.models import layers as L
@@ -44,7 +45,7 @@ def forward(params: Dict, cfg: RecsysConfig, dense: jnp.ndarray,
     z = jnp.einsum("bfd,bgd->bfg", feats, feats,
                    preferred_element_type=jnp.float32)         # (B, F, F)
     n_f = feats.shape[1]
-    iu, ju = jnp.triu_indices(n_f, k=1)
+    iu, ju = np.triu_indices(n_f, k=1)          # constants, not ops
     inter = z[:, iu, ju].astype(cdt)                           # (B, F(F-1)/2)
     top_in = jnp.concatenate([bot, inter], axis=-1)
     out = L.mlp_apply(params["top_mlp"], top_in, compute_dtype=cdt)

@@ -81,9 +81,10 @@ class ServingEngine:
         ``evaluate_chunk`` protocol callable is host-side numpy (every
         ``serving.evaluators`` backend is already traceable, so passing
         it for both is the common case). ``fused_max_evals`` caps the
-        fused evaluator batch width (default: the full padded batch —
-        always tier-exact; a smaller cap saves evaluator FLOPs on
-        warm-cache traffic but demotes overflow evals to the prior).
+        evaluations of a fused step (default: the full padded batch —
+        always tier-exact; a smaller cap demotes overflow evals to the
+        prior). The step evaluates only the slices that hold evaluated
+        rows either way.
 
         ``retriever`` (a ``retrieval.CorpusSearcher`` or anything with
         ``search(query, n) -> SearchResults``) enables

@@ -10,8 +10,9 @@ from _hypothesis_compat import given, settings, st
 
 from repro.configs.base import TrustIRConfig
 from repro.core import (FusedLoadShedder, LoadShedder, Regime, SimClock,
-                        TIER_EVAL, TIER_INVALID, TIER_PRIOR)
+                        TIER_CACHED, TIER_EVAL, TIER_INVALID, TIER_PRIOR)
 from repro.core import trust_cache as TC
+from repro.core.fused_shedder import MAX_SLICES
 from repro.scheduling import SchedulerConfig
 from repro.serving.engine import ServingEngine
 
@@ -145,6 +146,86 @@ def test_max_evals_overflow_demotes_to_prior_never_drops():
     assert (res.tier[:96] != TIER_INVALID).all()
     assert np.all(res.trust[res.tier == TIER_PRIOR]
                   == prior_at_decision)
+
+
+S = 64      # chunk_size of the sliced-evaluation cases
+
+# (n_total, n_valid, cached rows, max_evals, evaluated rows). Ucapacity
+# and Uthreshold are each half of n_total (at least 256), so every batch
+# is Normal and each uncached valid row is evaluated unless max_evals
+# demotes it; "wide_batch" packs over MAX_SLICES chunks.
+SLICE_CASES = {
+    "none_all_cached": (256, 40, "all", None, 0),
+    "one": (256, 1, None, None, 1),
+    "slice_less_one": (256, S - 1, None, None, S - 1),
+    "one_slice": (256, S, None, None, S),
+    "slice_plus_one_with_gaps": (256, 2 * S + 2, "odd", None, S + 1),
+    "all_rows": (256, 256, None, None, 256),
+    "ragged_n_total": (200, 200, None, None, 200),
+    "max_evals_32": (256, 96, None, 32, 32),
+    "wide_batch": (MAX_SLICES * S * 2, 200, None, None, 200),
+}
+
+
+@pytest.mark.parametrize("case", list(SLICE_CASES))
+def test_evaluator_runs_only_the_evaluated_slices(case):
+    """The step evaluates the compacted prefix in slices of chunk_size
+    rows (whole chunks more where a full micro-batch would take over
+    MAX_SLICES slices): the same tiers, and trust within 1e-6 of one
+    full-row evaluation of the batch, while the evaluator only ever
+    sees a slice's rows."""
+    n_total, n_valid, cached, max_evals, n_evald = SLICE_CASES[case]
+    half = max(256, n_total // 2)
+    cfg = _cfg(chunk_size=S, u_capacity=half, u_threshold=half,
+               cache_slots=1 << 16, cache_ways=4)
+    traced_rows = []
+
+    def ev(chunk):
+        traced_rows.append(chunk["x"].shape[0])
+        return _ev(chunk)
+
+    fused = FusedLoadShedder(cfg, ev, max_evals=max_evals,
+                             sim_clock=SimClock(cfg.u_capacity
+                                                / cfg.deadline_s))
+    keys, buckets, feats = _batch(n_valid, n_total, 3000)
+    keys_j = jnp.asarray(keys, jnp.uint32)
+    if cached is not None:
+        pick = np.zeros(n_total, bool)
+        pick[:n_valid] = True
+        if cached == "odd":
+            pick[0::2] = False
+        fused.cache = TC.insert(fused.cache, keys_j,
+                                jnp.full((n_total,), 4.25),
+                                jnp.asarray(pick))
+    cval, hit = (np.asarray(a) for a in TC.lookup(fused.cache, keys_j))
+    prior = np.asarray(fused.prior["mean"]).copy()
+
+    res = fused.process(keys, buckets, feats, n_valid=n_valid)
+
+    # The reference: every uncached valid row in arrival order is
+    # evaluated up to max_evals, scored by one full-batch forward.
+    valid = np.arange(n_total) < n_valid
+    hit = hit & valid
+    want_eval = valid & ~hit
+    want_eval &= np.cumsum(want_eval) <= (max_evals or n_total)
+    tier = np.where(hit, TIER_CACHED, TIER_PRIOR)
+    tier = np.where(want_eval, TIER_EVAL, tier)
+    tier = np.where(valid, tier, TIER_INVALID)
+    full = np.asarray(_ev({"x": jnp.asarray(feats["x"])}))
+    trust = np.where(tier == TIER_EVAL, full,
+                     np.where(tier == TIER_CACHED, cval,
+                              prior[buckets % len(prior)]))
+    trust = np.where(valid, trust, 0.0)
+    assert np.array_equal(res.tier, tier)
+    np.testing.assert_allclose(res.trust, trust, rtol=0, atol=1e-6)
+
+    assert res.n_evaluated == int(want_eval.sum()) == n_evald
+    rows = S * -(-2 * half // (MAX_SLICES * S))
+    assert rows == (2 * S if case == "wide_batch" else S)
+    assert res.n_eval_rows == -(-n_evald // rows) * rows
+    assert traced_rows and set(traced_rows) == {rows}
+    if max_evals is not None:       # overflow demoted, never dropped
+        assert res.n_prior == n_valid - max_evals
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ import pytest
 from repro import obs
 from repro.cluster import ClusterConfig, ClusterCoordinator
 from repro.configs.base import TrustIRConfig
+from repro.core.fused_shedder import MAX_SLICES
 from repro.scheduling import Priority
 
 D = 8
@@ -190,8 +191,12 @@ def test_counters_are_the_sums_of_the_run(drain_mode):
     assert s["n_eval_rows"] >= s["n_evaluated"]
     cs = coord.cfg.chunk_size
     assert all(r.n_eval_rows % cs == 0 for r in sheds)
-    if drain_mode == "fused":            # max_evals: the padded batch
-        assert all(r.n_eval_rows == len(r.tier) for r in sheds)
+    if drain_mode == "fused":    # the evaluated slices, not the batch
+        cfg = coord.cfg
+        assert cfg.u_capacity + cfg.u_threshold <= MAX_SLICES * cs
+        assert all(r.n_eval_rows == -(-r.n_evaluated // cs) * cs
+                   <= len(r.tier) for r in sheds)
+        assert any(r.n_eval_rows < len(r.tier) for r in sheds)
     admitted = [r for r in coord.completed if r.admitted]
     assert s["n_queue_waits"] == len(admitted)
     assert s["queue_wait_s"] == pytest.approx(
