@@ -74,7 +74,41 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001     # load-balance loss coefficient
     norm_topk_prob: bool = True        # renormalize top-k gate weights
-    dispatch: str = "dense_scatter"    # "dense_scatter" | "ep_shard_map"
+    # "dense_scatter" | "ep_shard_map" (both cut each expert at a
+    # capacity) | "dropless" (the held experts' grouped matmul, no cut)
+    dispatch: str = "dense_scatter"
+    # The router takes the top_k of its logits and softmaxes over those
+    # (granite) instead of taking the top_k of a softmax over all.
+    topk_then_softmax: bool = False
+    # Experts this device holds (dropless only): experts
+    # [held_offset, held_offset + n_held) of the router's n_experts.
+    # 0 holds them all.
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer (state-space duality form)."""
+    n_heads: int
+    d_head: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    conv_bias: bool = True
+    chunk_size: int = 256
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.d_state
 
 
 @dataclass(frozen=True)
@@ -102,6 +136,16 @@ class TransformerConfig:
     query_pre_attn_scalar: float = 0.0 # gemma2 overrides 1/sqrt(d_head)
     # MoE
     moe: Optional[MoEConfig] = None
+    # Hybrid stacks (granite-4.0-h): each layer's mixer, "attention" or
+    # "mamba"; empty means every layer is attention. Set only from a
+    # configuration file.
+    layer_kinds: Tuple[str, ...] = ()
+    ssm: Optional[SSMConfig] = None
+    use_rope: bool = True              # False: no positional embedding
+    attn_scale: float = 0.0            # >0 overrides 1/sqrt(d_head)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0   # each branch adds multiplier * out
+    logits_scaling: float = 1.0        # logits are divided by it
     # numerics / execution
     dtype: str = "bfloat16"
     param_dtype: str = "float32"

@@ -129,11 +129,13 @@ class PendingShed:
                  n_evald, *, t_start: float, wall_start: float,
                  n: int, regime, deadline_eff: float,
                  skip_observe: bool = False,
-                 item_keys: Optional[np.ndarray] = None, seq: int = -1):
+                 item_keys: Optional[np.ndarray] = None, seq: int = -1,
+                 counts: Optional[Dict] = None):
         self._shedder = shedder
         self._trust = trust
         self._tier = tier
         self._n_evald = n_evald
+        self._counts = counts or {}
         self._item_keys = item_keys
         self._t_start = t_start
         self._wall_start = wall_start
@@ -216,9 +218,11 @@ class FusedLoadShedder(LoadShedder):
         if apply is None:
             self._apply = lambda _params, feats: evaluate_batch(feats)
             self._eval_params = None
+            self._count_names = ()
         else:
             self._apply = apply
             self._eval_params = evaluate_batch.params
+            self._count_names = tuple(getattr(evaluate_batch, "counts", ()))
         self._mesh = _mesh_of(self._eval_params)
         if self._mesh is not None:
             # Start the state where the step leaves it (replicated on
@@ -291,14 +295,29 @@ class FusedLoadShedder(LoadShedder):
         with jax.named_scope("evaluate"):
             # Only the slices that hold evaluated rows run: the step's
             # evaluator cost follows the rows served, not n_total.
-            def one_slice(i, scores):
+            def slice_features(i):
                 sl = jax.lax.dynamic_slice_in_dim(gidx, i * s, s)
-                sub = jax.tree.map(lambda a: a[sl], features)
-                out = self._apply(eval_params, sub).astype(jnp.float32)
+                return jax.tree.map(lambda a: a[sl], features)
+
+            def put(scores, out, i):
                 return jax.lax.dynamic_update_slice_in_dim(
-                    scores, out, i * s, 0)
-            scores = jax.lax.fori_loop(0, n_slices, one_slice,
-                                       jnp.zeros((rows,), jnp.float32))
+                    scores, out.astype(jnp.float32), i * s, 0)
+
+            # An evaluator that names counts (``Evaluator.counts``)
+            # returns them beside its scores, summed here over the
+            # slices; for the others the dict is empty.
+            names = self._count_names
+
+            def one_slice(i, carry):
+                scores, counts = carry
+                out = self._apply(eval_params, slice_features(i))
+                out, c = out if names else (out, {})
+                return (put(scores, out, i),
+                        {k: counts[k] + c[k] for k in names})
+            scores, counts = jax.lax.fori_loop(
+                0, n_slices, one_slice,
+                (jnp.zeros((rows,), jnp.float32),
+                 {k: jnp.zeros((), jnp.int32) for k in names}))
         with jax.named_scope("scatter_combine"):
             scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
                 jnp.where(eval_valid, scores, 0.0),
@@ -311,8 +330,11 @@ class FusedLoadShedder(LoadShedder):
         with jax.named_scope("prior_update"):
             new_prior = AT.update(prior, buckets, trust, evald,
                                   ewma=self.cfg.prior_ewma)
-        return (trust, tier, jnp.sum(evald.astype(jnp.int32)),
-                new_cache, new_prior)
+        out = (trust, tier, jnp.sum(evald.astype(jnp.int32)), new_cache,
+               new_prior)
+        # The counts come sixth, and only where the evaluator names some:
+        # the others' step keeps its five outputs.
+        return out + (counts,) if names else out
 
     # -- stage / dispatch / finish --------------------------------------------
     def stage(self, item_keys: np.ndarray, buckets: np.ndarray,
@@ -370,7 +392,7 @@ class FusedLoadShedder(LoadShedder):
         warm = self._warmup.warm(
             WarmupGate.signature(n_total, staged.feats_j)
             + (max_evals,))
-        trust, tier, n_evald, self.cache, self.prior = self._step(
+        trust, tier, n_evald, self.cache, self.prior, *counts = self._step(
             self.cache, self.prior, self._eval_params, staged.keys_j,
             staged.buckets_j, staged.valid_j, staged.feats_j, ucap, uthr,
             budget_total, max_evals=max_evals)
@@ -381,7 +403,8 @@ class FusedLoadShedder(LoadShedder):
                               deadline_eff=deadline_eff,
                               skip_observe=not warm,
                               item_keys=staged.item_keys,
-                              seq=staged.seq)
+                              seq=staged.seq,
+                              counts=counts[0] if counts else None)
         if self.sim_clock is not None:
             pending.result()
         return pending
@@ -401,6 +424,7 @@ class FusedLoadShedder(LoadShedder):
             trust = np.asarray(p._trust)            # sync point
             tier = np.asarray(p._tier)
             n_evald = int(p._n_evald)
+            counts = {k: int(v) for k, v in p._counts.items()}
         wall_end = time.monotonic()
         if self.sim_clock is not None:
             self.sim_clock.charge_probe()
@@ -439,7 +463,9 @@ class FusedLoadShedder(LoadShedder):
             n_evaluated=n_evald,
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=p._n, n_eval_rows=self._slice_cover(n_evald))
+            uload=p._n, n_eval_rows=self._slice_cover(n_evald),
+            n_expert_rows=counts.get("expert_rows", 0),
+            n_expert_slots=counts.get("expert_slots", 0))
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None and p._item_keys is not None:

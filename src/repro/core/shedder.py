@@ -210,6 +210,11 @@ class ShedResult:
     # loop's chunks times the chunk size. 0 for a request's share of a
     # batch.
     n_eval_rows: int = 0
+    # An MoE evaluator's counts over the batch's slices and layers: the
+    # (token, expert) rows its held experts computed, and the rows its
+    # grouped matmuls ran, padding included. 0 for other evaluators.
+    n_expert_rows: int = 0
+    n_expert_slots: int = 0
 
     @property
     def no_item_dropped(self) -> bool:

@@ -1,14 +1,25 @@
 """Mixture-of-Experts FFN with top-k routing.
 
-Dispatch is sort-based (MegaBlocks-style grouped compute adapted to TPU):
-tokens are argsorted by destination expert, scattered into a fixed
-``(n_experts, capacity, d_model)`` buffer (static shapes — XLA/SPMD
-friendly), pushed through a grouped SwiGLU einsum, and scattered back.
-Tokens beyond an expert's capacity are *dropped from expert compute* and
-keep only the residual path — under TrustServe's ladder this is exactly
-the paper's PRIOR tier applied at the expert level (DESIGN.md §4).
+Three dispatches (``MoEConfig.dispatch``):
 
-The ``(E, C, D)`` buffer shards cleanly: E over the ``model`` axis (EP).
+* ``dense_scatter``: sort-based (MegaBlocks-style grouped compute adapted
+  to TPU): tokens are argsorted by destination expert, scattered into a
+  fixed ``(n_experts, capacity, d_model)`` buffer (static shapes —
+  XLA/SPMD friendly), pushed through a grouped SwiGLU einsum, and
+  scattered back. The ``(E, C, D)`` buffer shards cleanly: E over the
+  ``model`` axis (EP).
+* ``ep_shard_map``: the same per expert-parallel shard (below).
+* ``dropless``: the experts this device holds, as a grouped matmul over
+  their sorted (token, expert) rows (:func:`dropless_apply`); no token is
+  ever cut.
+
+The first two cut each expert at a capacity: a (token, expert) pair past
+it is *dropped from expert compute* and keeps only the residual path.
+That is a different answer, not a degraded one: a dropped token's output
+depends on which other tokens share its batch, and nothing reports it
+(no PRIOR tier, no reason). So they serve training, where
+``moe_drop_frac`` watches it, and an evaluator whose answer must not
+depend on its batch (the trust scorer) uses ``dropless``.
 """
 from __future__ import annotations
 
@@ -50,8 +61,8 @@ def moe_apply(p: Dict, x: jnp.ndarray, cfg: MoEConfig, *,
               ) -> Tuple[jnp.ndarray, Dict]:
     """x: (T, D) flattened tokens -> (out (T, D), metrics dict).
 
-    Metrics carry the router aux loss (load balance) and the dropped-token
-    fraction (the PRIOR-tier rate).
+    Metrics carry the router aux loss (load balance) and the fraction of
+    (token, expert) pairs cut at capacity.
     """
     T, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -239,9 +250,108 @@ def moe_apply_ep(p: Dict, x: jnp.ndarray, cfg: MoEConfig, *,
                                                             jnp.float32)}
 
 
+# ---------------------------------------------------------------------------
+# Dropless held experts: the layer of one expert-parallel device. The
+# router keeps its full width; this device computes only its own
+# experts' part of the result, and what the others would add comes from
+# their devices (or, on one chip, is left out: no stand-in is computed).
+# ---------------------------------------------------------------------------
+
+def dropless_init(key, d_model: int, cfg: MoEConfig, dtype=jnp.float32
+                  ) -> Dict:
+    """Router over all ``n_experts``; the held experts' SwiGLU weights as
+    ``w_in`` (E_held, D, 2F: gate then up) and ``w_out`` (E_held, F, D)."""
+    ks = jax.random.split(key, 4)
+    E_h, F = cfg.held, cfg.d_expert
+    p = {
+        "router": {"w": L.trunc_normal(ks[0], (d_model, cfg.n_experts),
+                                       math.sqrt(1.0 / d_model), dtype)},
+        "w_in": L.trunc_normal(ks[1], (E_h, d_model, 2 * F),
+                               math.sqrt(1.0 / d_model), dtype),
+        "w_out": L.trunc_normal(ks[2], (E_h, F, d_model),
+                                math.sqrt(1.0 / F), dtype),
+    }
+    if cfg.n_shared_experts > 0:
+        d_sh = (cfg.d_shared or cfg.d_expert) * cfg.n_shared_experts
+        p["shared"] = L.glu_ffn_init(ks[3], d_model, d_sh, dtype)
+    return p
+
+
+def route(router_w: jnp.ndarray, x: jnp.ndarray, cfg: MoEConfig
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(gate weights (T, K) f32, expert ids (T, K)) over all experts."""
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    if cfg.topk_then_softmax:
+        top, idx = jax.lax.top_k(logits, cfg.top_k)
+        return jax.nn.softmax(top, axis=-1), idx
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    return w, idx
+
+
+def dropless_apply(p: Dict, x: jnp.ndarray, cfg: MoEConfig, *,
+                   act: str = "silu", compute_dtype=jnp.bfloat16
+                   ) -> Tuple[jnp.ndarray, Dict]:
+    """x: (T, D) -> (this device's part of the layer (T, D), counts).
+
+    The (token, expert) pairs routed to held experts are sorted by
+    expert to the front of the T*K pairs and run as one grouped matmul
+    (``jax.lax.ragged_dot``) per projection. Its rows are static, T *
+    min(K, E_held): each token has K distinct experts, so no more of its
+    pairs can land here, and none is ever cut. Each token's output reads
+    only its own rows, whatever else the batch holds. The shared expert
+    (when ``p`` has one) is added whole.
+
+    Counts (int32): ``expert_rows``, the pairs routed to held experts;
+    ``expert_slots``, the rows the grouped matmul ran, padding included.
+    """
+    T, D = x.shape
+    K, E_h = cfg.top_k, cfg.held
+    rows = T * min(K, E_h)
+    with jax.named_scope("moe_route"):
+        gate_w, idx = route(p["router"]["w"], x, cfg)
+        local = idx.reshape(T * K) - cfg.held_offset
+        mine = (local >= 0) & (local < E_h)
+        group = jnp.where(mine, local, E_h)                 # foreign last
+        order = jnp.argsort(group, stable=True)[:rows]
+        sizes = jnp.zeros((E_h + 1,), jnp.int32).at[group].add(1)[:E_h]
+        xs = x.astype(compute_dtype)[order // K]            # (rows, D)
+    with jax.named_scope("moe_experts"):
+        h = jax.lax.ragged_dot(xs, p["w_in"].astype(compute_dtype), sizes)
+        g, u = jnp.split(h, 2, axis=-1)
+        h = (jax.nn.silu(g) if act == "silu"
+             else jax.nn.gelu(g, approximate=True)) * u
+        y = jax.lax.ragged_dot(h, p["w_out"].astype(compute_dtype), sizes)
+    with jax.named_scope("moe_route"):
+        # Each held pair's row in the sorted order; rows past the groups
+        # are never read (``where``, not a product: they hold no value).
+        pos = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32), mode="drop")
+        vals = y[jnp.minimum(pos, rows - 1)]
+        w_flat = gate_w.reshape(T * K).astype(compute_dtype)
+        out = jnp.sum(jnp.where(mine[:, None], vals * w_flat[:, None], 0)
+                      .reshape(T, K, D), axis=1)
+    if "shared" in p:
+        out = out + L.glu_ffn_apply(p["shared"], x.astype(compute_dtype),
+                                    act=act, compute_dtype=compute_dtype)
+    return out.astype(x.dtype), {"expert_rows": jnp.sum(sizes),
+                                 "expert_slots": jnp.int32(rows)}
+
+
+def init(key, d_model: int, cfg: MoEConfig, dtype=jnp.float32) -> Dict:
+    """Parameters in the layout of ``cfg.dispatch``."""
+    if cfg.dispatch == "dropless":
+        return dropless_init(key, d_model, cfg, dtype)
+    return moe_init(key, d_model, cfg, dtype)
+
+
 def apply(p: Dict, x: jnp.ndarray, cfg: MoEConfig, *, act: str = "silu",
           compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Dict]:
     """Dispatch-mode switch (``MoEConfig.dispatch``)."""
+    if cfg.dispatch == "dropless":
+        return dropless_apply(p, x, cfg, act=act,
+                              compute_dtype=compute_dtype)
     if cfg.dispatch == "ep_shard_map":
         return moe_apply_ep(p, x, cfg, act=act,
                             compute_dtype=compute_dtype)

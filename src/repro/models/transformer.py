@@ -6,6 +6,13 @@ Features driven entirely by ``TransformerConfig``:
   - gemma2: alternating local/global attention, attention + final logit
     softcaps, pre+post RMSNorm, sqrt(d_model) embedding scale, query
     pre-attention scalar,
+  - hybrid stacks (granite-4.0-h): ``layer_kinds`` names each layer's
+    mixer, Mamba-2 (``models.ssm``) or attention (optionally without
+    positions and with a configured score scale), each followed by an MoE;
+    embedding, residual-branch and logit multipliers; a tied head. Consecutive layers
+    of one kind are one ``lax.scan``, so the program grows with the
+    pattern's runs, not its depth. Scoring only (``hidden_states``,
+    ``score_tokens``),
   - ``scan_layers``: layers stacked and executed with ``lax.scan`` so HLO
     size is O(1) in depth (required for 48-layer full configs to compile
     quickly in the dry-run), with ``jax.checkpoint`` remat per block,
@@ -25,36 +32,68 @@ from repro.configs.base import TransformerConfig
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
+from repro.models import ssm as SSM
 
 
 # ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
 
+def _attn_init(ks, cfg: TransformerConfig, dt) -> Dict:
+    d, Hq, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    return {
+        "wq": L.dense_init(ks[0], d, Hq * Dh, bias=cfg.qkv_bias, dtype=dt),
+        "wk": L.dense_init(ks[1], d, Hkv * Dh, bias=cfg.qkv_bias, dtype=dt),
+        "wv": L.dense_init(ks[2], d, Hkv * Dh, bias=cfg.qkv_bias, dtype=dt),
+        "wo": L.dense_init(ks[3], Hq * Dh, d, dtype=dt,
+                           std=math.sqrt(1.0 / (Hq * Dh))
+                           / math.sqrt(2.0 * cfg.n_layers)),
+    }
+
+
 def _block_init(key, cfg: TransformerConfig, moe_layer: bool,
                 d_ff_override: int = 0) -> Dict:
     ks = jax.random.split(key, 8)
     dt = L.dtype_of(cfg.param_dtype)
-    d, Hq, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    d = cfg.d_model
     p = {
         "ln1": L.rmsnorm_init(d, dt),
         "ln2": L.rmsnorm_init(d, dt),
-        "attn": {
-            "wq": L.dense_init(ks[0], d, Hq * Dh, bias=cfg.qkv_bias, dtype=dt),
-            "wk": L.dense_init(ks[1], d, Hkv * Dh, bias=cfg.qkv_bias, dtype=dt),
-            "wv": L.dense_init(ks[2], d, Hkv * Dh, bias=cfg.qkv_bias, dtype=dt),
-            "wo": L.dense_init(ks[3], Hq * Dh, d, dtype=dt,
-                               std=math.sqrt(1.0 / (Hq * Dh))
-                               / math.sqrt(2.0 * cfg.n_layers)),
-        },
+        "attn": _attn_init(ks, cfg, dt),
     }
     if cfg.post_norm:
         p["ln1_post"] = L.rmsnorm_init(d, dt)
         p["ln2_post"] = L.rmsnorm_init(d, dt)
     if moe_layer:
-        p["moe"] = M.moe_init(ks[4], d, cfg.moe, dt)
+        p["moe"] = M.init(ks[4], d, cfg.moe, dt)
     else:
         p["ffn"] = L.glu_ffn_init(ks[4], d, d_ff_override or cfg.d_ff, dt)
+    return p
+
+
+def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[str, int], ...]:
+    """A hybrid stack's consecutive layers of one kind: (kind, count)."""
+    runs = []
+    for kind in cfg.layer_kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return tuple((k, n) for k, n in runs)
+
+
+def _hybrid_layer_init(key, cfg: TransformerConfig, kind: str) -> Dict:
+    k_mix, k_ffn = jax.random.split(key)
+    dt = L.dtype_of(cfg.param_dtype)
+    d = cfg.d_model
+    p = {"ln1": L.rmsnorm_init(d, dt), "ln2": L.rmsnorm_init(d, dt)}
+    if kind == "mamba":
+        p["mamba"] = SSM.mamba2_init(k_mix, d, cfg.ssm, dt)
+    elif kind == "attention":
+        p["attn"] = _attn_init(jax.random.split(k_mix, 4), cfg, dt)
+    else:
+        raise ValueError(f"unknown layer kind {kind!r}")
+    p["moe"] = M.init(k_ffn, d, cfg.moe, dt)
     return p
 
 
@@ -63,6 +102,15 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
     k_emb, k_blocks, k_unemb = jax.random.split(key, 3)
     params: Dict = {"embed": L.embed_init(k_emb, cfg.vocab_size,
                                           cfg.d_model, dt)}
+    if cfg.layer_kinds:
+        # One stacked parameter tree per run of same-kind layers.
+        keys = iter(jax.random.split(k_blocks, cfg.n_layers))
+        params["runs"] = [
+            jax.vmap(lambda k, kind=kind: _hybrid_layer_init(k, cfg, kind))(
+                jnp.stack([next(keys) for _ in range(n)]))
+            for kind, n in layer_runs(cfg)]
+        params["final_norm"] = L.rmsnorm_init(cfg.d_model, dt)
+        return params
     first_dense = cfg.moe.first_k_dense if cfg.moe else 0
     n_scan = cfg.n_layers - first_dense
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
@@ -104,6 +152,8 @@ def layer_windows(cfg: TransformerConfig) -> jnp.ndarray:
 
 
 def _attn_scale(cfg: TransformerConfig) -> float:
+    if cfg.attn_scale > 0:
+        return cfg.attn_scale
     if cfg.query_pre_attn_scalar > 0:
         return cfg.query_pre_attn_scalar ** -0.5
     return cfg.d_head ** -0.5
@@ -132,8 +182,9 @@ def _qkv(bp: Dict, cfg: TransformerConfig, x: jnp.ndarray, positions,
     q = q.reshape(*x.shape[:-1], cfg.n_heads, cfg.d_head)
     k = k.reshape(*x.shape[:-1], cfg.n_kv_heads, cfg.d_head)
     v = v.reshape(*x.shape[:-1], cfg.n_kv_heads, cfg.d_head)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -188,11 +239,61 @@ def _block_fwd(bp: Dict, cfg: TransformerConfig, x: jnp.ndarray,
     return x + f, metrics
 
 
+def _hybrid_layer(lp: Dict, kind: str, cfg: TransformerConfig,
+                  x: jnp.ndarray, positions: jnp.ndarray, compute_dtype,
+                  q_chunk: int) -> Tuple[jnp.ndarray, Dict]:
+    """One layer of a hybrid stack: mixer, then MoE, each branch added as
+    ``x + residual_multiplier * branch``. x: (B, S, D)."""
+    rm = cfg.residual_multiplier
+    h = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
+    if kind == "mamba":
+        with jax.named_scope("mamba"):
+            o = SSM.mamba2_apply(lp["mamba"], h, cfg.ssm, eps=cfg.norm_eps,
+                                 compute_dtype=compute_dtype)
+    else:
+        with jax.named_scope("attention"):
+            q, k, v = _qkv_tp(lp, cfg, h, positions, compute_dtype)
+            o = A.attention(q, k, v, causal=True, window=0,
+                            softcap=cfg.attn_logit_softcap,
+                            scale=_attn_scale(cfg), q_chunk=q_chunk)
+            o = L.dense_apply(
+                lp["attn"]["wo"],
+                o.reshape(*x.shape[:-1], cfg.n_heads * cfg.d_head),
+                compute_dtype)
+    x = x + o * rm
+    h = L.rmsnorm_apply(lp["ln2"], x, cfg.norm_eps)
+    B, S, D = h.shape
+    f, metrics = M.apply(lp["moe"], h.reshape(B * S, D), cfg.moe,
+                         act=cfg.act, compute_dtype=compute_dtype)
+    return x + f.reshape(B, S, D) * rm, metrics
+
+
+def _hybrid_stack(params: Dict, cfg: TransformerConfig, x: jnp.ndarray,
+                  positions: jnp.ndarray, compute_dtype, q_chunk: int
+                  ) -> Tuple[jnp.ndarray, Dict]:
+    """The layers of ``cfg.layer_kinds``, one ``lax.scan`` per run of
+    same-kind layers; metrics summed over every layer."""
+    metrics: Dict = {}
+    for (kind, _), run in zip(layer_runs(cfg), params["runs"]):
+        def step(carry, lp, kind=kind):
+            return _hybrid_layer(lp, kind, cfg, carry, positions,
+                                 compute_dtype, q_chunk)
+
+        x, ms = jax.lax.scan(step, x, run)
+        metrics = _acc_metrics(metrics, {k: jnp.sum(v, axis=0)
+                                         for k, v in ms.items()})
+    return x, metrics
+
+
 def _zero_metrics(cfg: TransformerConfig) -> Dict:
-    if cfg.moe is not None:
-        return {"moe_aux_loss": jnp.zeros((), jnp.float32),
-                "moe_drop_frac": jnp.zeros((), jnp.float32)}
-    return {}
+    """The metrics the stack's MoE layers return, at zero."""
+    if cfg.moe is None:
+        return {}
+    if cfg.moe.dispatch == "dropless":
+        return {"expert_rows": jnp.zeros((), jnp.int32),
+                "expert_slots": jnp.zeros((), jnp.int32)}
+    return {"moe_aux_loss": jnp.zeros((), jnp.float32),
+            "moe_drop_frac": jnp.zeros((), jnp.float32)}
 
 
 def _acc_metrics(acc: Dict, m: Dict) -> Dict:
@@ -267,6 +368,12 @@ def hidden_states(params: Dict, cfg: TransformerConfig,
     x = L.embed_apply(params["embed"], tokens, cdt)
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cdt)
+    if cfg.layer_kinds:
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        x, metrics = _hybrid_stack(params, cfg, x, positions, cdt, q_chunk)
+        return L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps), \
+            metrics
     windows = layer_windows(cfg)
     metrics = _zero_metrics(cfg)
 
@@ -404,6 +511,8 @@ def target_logprobs(params: Dict, cfg: TransformerConfig, x: jnp.ndarray,
         m, s, tgt = carry
         w_c, base = xs
         logits = x @ w_c.astype(x.dtype).T                   # (B, S, vc)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.final_logit_softcap > 0:
             logits = L.softcap(logits, cfg.final_logit_softcap)
         logits = logits.astype(jnp.float32)
@@ -426,21 +535,24 @@ def target_logprobs(params: Dict, cfg: TransformerConfig, x: jnp.ndarray,
 
 def score_tokens(params: Dict, cfg: TransformerConfig, tokens: jnp.ndarray,
                  mask: Optional[jnp.ndarray] = None, q_chunk: int = 1024,
-                 vocab_chunk: int = 2048) -> jnp.ndarray:
+                 vocab_chunk: int = 2048, return_metrics: bool = False):
     """Sequence log-likelihood score, the LM trust-evaluator head.
 
     Returns per-sequence mean token logprob (B,) — mapped to a
-    trustworthiness value by the core pipeline. The vocabulary
-    logsumexp is chunked (:func:`target_logprobs`).
+    trustworthiness value by the core pipeline — and, with
+    ``return_metrics``, the layers' summed metrics beside it. The
+    vocabulary logsumexp is chunked (:func:`target_logprobs`).
     """
-    x, _ = hidden_states(params, cfg, tokens[:, :-1], q_chunk=q_chunk)
+    x, metrics = hidden_states(params, cfg, tokens[:, :-1], q_chunk=q_chunk)
     tok_lp = target_logprobs(params, cfg, x, tokens[:, 1:],
                              vocab_chunk=vocab_chunk)
     if mask is not None:
         m = mask[:, 1:].astype(jnp.float32)
-        return jnp.sum(tok_lp * m, axis=-1) / jnp.maximum(
+        score = jnp.sum(tok_lp * m, axis=-1) / jnp.maximum(
             jnp.sum(m, axis=-1), 1.0)
-    return jnp.mean(tok_lp, axis=-1)
+    else:
+        score = jnp.mean(tok_lp, axis=-1)
+    return (score, metrics) if return_metrics else score
 
 
 # ---------------------------------------------------------------------------

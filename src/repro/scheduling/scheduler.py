@@ -112,6 +112,10 @@ class SchedulerStats:
     n_eval_rows: int = 0
     n_evaluated: int = 0
     n_cached: int = 0
+    # An MoE evaluator's expert rows computed and rows run (padding
+    # included), summed over slices and layers; 0 without experts.
+    n_expert_rows: int = 0
+    n_expert_slots: int = 0
     # Summed queue_delay_s of the responses batches produced, and their
     # number (rescued batches included).
     queue_wait_s: float = 0.0
@@ -130,6 +134,8 @@ class SchedulerStats:
                 "n_eval_rows": self.n_eval_rows,
                 "n_evaluated": self.n_evaluated,
                 "n_cached": self.n_cached,
+                "n_expert_rows": self.n_expert_rows,
+                "n_expert_slots": self.n_expert_slots,
                 "queue_wait_s": self.queue_wait_s,
                 "n_queue_waits": self.n_queue_waits,
                 "mean_batch_fill": (self.n_batched_items
@@ -448,6 +454,8 @@ class Scheduler:
         self.stats.n_eval_rows += shed.n_eval_rows
         self.stats.n_evaluated += shed.n_evaluated
         self.stats.n_cached += shed.n_cached
+        self.stats.n_expert_rows += shed.n_expert_rows
+        self.stats.n_expert_slots += shed.n_expert_slots
         if self.quarantine is not None and self.quarantine.any_tracked:
             # Clean completion: decay strikes / close half-open probes
             # for every signature this batch carried.

@@ -20,6 +20,7 @@ using — no device-side reshard on the hot path.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -36,15 +37,24 @@ class Evaluator:
     and the parameters it runs with. Calling it scores one feature
     batch. The fused drain passes ``params`` into its jitted step as an
     argument (``core.fused_shedder``): a closure over them would fold
-    every weight into the compiled step as a constant."""
+    every weight into the compiled step as a constant.
 
-    def __init__(self, apply: Callable, params: Any):
+    ``counts`` names int32 device scalars (an MoE evaluator's
+    ``expert_rows`` and ``expert_slots``) that ``apply`` then returns
+    beside the scores, as ``(scores, {name: count})``; the fused step
+    sums them over its slices. Calling the evaluator gives the scores
+    alone."""
+
+    def __init__(self, apply: Callable, params: Any,
+                 counts: Tuple[str, ...] = ()):
         self.apply = apply
         self.params = params
+        self.counts = tuple(counts)
         self._jitted = jax.jit(apply)
 
     def __call__(self, features) -> jnp.ndarray:
-        return self._jitted(self.params, features)
+        out = self._jitted(self.params, features)
+        return out[0] if self.counts else out
 
 
 def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
@@ -61,6 +71,11 @@ def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,
 
     if isinstance(cfg, TransformerConfig):
         from repro.models import transformer as T
+        if cfg.moe is not None and place_params is None:
+            # A score must not depend on the batch around it: experts
+            # cut at a capacity would make it so (``models.moe``).
+            cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+                cfg.moe, dispatch="dropless"))
         params = _place(T.init_params(key, cfg), cfg)
 
         def apply(params, chunk: Dict) -> jnp.ndarray:

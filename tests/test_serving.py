@@ -14,7 +14,8 @@ from repro.serving.kv_cache import KVCachePool, SlotAllocator
 from repro.serving.simulator import WorkloadConfig, run_workload
 
 ALL_ARCHS = ["smollm-135m", "gemma2-2b", "gcn-cora", "dlrm-mlperf",
-             "bst", "two-tower-retrieval", "mind"]
+             "bst", "two-tower-retrieval", "mind", "qwen3-moe-30b-a3b",
+             "moonshot-v1-16b-a3b"]
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
