@@ -1105,7 +1105,7 @@ class ClusterCoordinator:
                        "n_batches", "n_batched_items", "n_hedges",
                        "n_executor_errors", "n_quarantined",
                        "n_eval_rows", "n_evaluated", "n_cached",
-                       "n_expert_rows", "n_expert_slots",
+                       "n_expert_rows", "n_expert_slots", "xfer_s",
                        "queue_wait_s", "n_queue_waits")
 
     @classmethod

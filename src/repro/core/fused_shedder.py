@@ -26,11 +26,15 @@ compiled step. The cache and prior are donated: after a dispatch only
 the step's outputs (``self.cache``/``self.prior``) are live, so code
 that keeps a state across a dispatch must copy it first.
 
-Features transfer to device once per *batch* (the host path converts
-the pytree then re-gathers per chunk), and the transfer is its own
-stage: ``stage`` enqueues the host->device copies, ``dispatch_staged``
-launches the step, and ``process_async`` composes the two into a
-:class:`PendingShed` whose arrays stay on device until ``.result()``.
+Each batch crosses between host and device twice: ``stage`` uploads its
+keys, buckets, valid mask and every feature leaf with one
+``jax.device_put`` of the tuple (the host path converts the pytree then
+re-gathers per chunk), and ``dispatch_staged`` launches the step and at
+once starts the readback of its answers (``copy_to_host_async`` on
+trust, tier, the evaluated count and the evaluator's counts), which
+``.result()`` collects with one ``jax.device_get``. ``process_async``
+composes stage and dispatch into a :class:`PendingShed`. The serving
+thread's seconds in the two transfers are the batch's ``xfer_s``.
 The ``scheduling.executor.DrainExecutor`` sequences these handles in a
 depth-k in-flight window (``TrustIRConfig.pipeline_depth``): batch N+2
 forms and transfers while batch N computes and N+1 waits, and at depth
@@ -98,11 +102,12 @@ class StagedBatch:
     """
 
     __slots__ = ("item_keys", "keys_j", "buckets_j", "valid_j",
-                 "feats_j", "n", "n_total", "t_start", "wall_start", "seq")
+                 "feats_j", "n", "n_total", "t_start", "wall_start", "seq",
+                 "stage_s")
 
     def __init__(self, item_keys, keys_j, buckets_j, valid_j, feats_j,
                  n: int, n_total: int, t_start: float,
-                 wall_start: float, seq: int = -1):
+                 wall_start: float, seq: int = -1, stage_s: float = 0.0):
         self.item_keys = item_keys
         self.keys_j = keys_j
         self.buckets_j = buckets_j
@@ -113,16 +118,18 @@ class StagedBatch:
         self.t_start = t_start
         self.wall_start = wall_start
         self.seq = seq
+        self.stage_s = stage_s
 
 
 class PendingShed:
     """Handle to an in-flight fused shedding step.
 
     ``trust``/``tier`` stay device-resident (possibly still computing —
-    JAX async dispatch) until :meth:`result` materializes them, charges
-    the clock/monitor, and builds the :class:`ShedResult`. ``seq`` is
-    the dispatcher's batch sequence number, carried into the spans of
-    the batch's sync and fold-back.
+    JAX async dispatch), their copies to the host already requested,
+    until :meth:`result` collects them, charges the clock/monitor, and
+    builds the :class:`ShedResult`. ``seq`` is the dispatcher's batch
+    sequence number, carried into the spans of the batch's sync and
+    fold-back; ``stage_s`` the seconds its upload took.
     """
 
     def __init__(self, shedder: "FusedLoadShedder", trust, tier,
@@ -130,7 +137,7 @@ class PendingShed:
                  n: int, regime, deadline_eff: float,
                  skip_observe: bool = False,
                  item_keys: Optional[np.ndarray] = None, seq: int = -1,
-                 counts: Optional[Dict] = None):
+                 counts: Optional[Dict] = None, stage_s: float = 0.0):
         self._shedder = shedder
         self._trust = trust
         self._tier = tier
@@ -144,6 +151,7 @@ class PendingShed:
         self._deadline_eff = deadline_eff
         self._skip_observe = skip_observe
         self.seq = seq
+        self._stage_s = stage_s
         self._result: Optional[ShedResult] = None
         # Wall time at which the step was FIRST observed complete
         # (stamped by is_ready): the honest end of the throughput
@@ -341,33 +349,38 @@ class FusedLoadShedder(LoadShedder):
               features, n_valid: Optional[int] = None,
               seq: int = -1) -> StagedBatch:
         """Front half of the fused step: ONE host->device transfer per
-        batch (the host path re-gathers from the feature pytree once
-        per chunk). The copies are enqueued asynchronously, so under a
-        depth-k executor the transfer of batch N+2 runs behind batch
-        N's in-flight compute — the transfer half of the pipeline.
-        ``seq`` (the executor's batch sequence number) travels with the
-        batch to its sync and fold-back spans."""
+        batch, a ``jax.device_put`` of keys, buckets, valid mask and
+        every feature leaf together (the host path re-gathers from the
+        feature pytree once per chunk). The copies are enqueued
+        asynchronously, so under a depth-k executor the transfer of
+        batch N+2 runs behind batch N's in-flight compute — the
+        transfer half of the pipeline. Without a ``feature_sharding``
+        every array lands uncommitted on the default device, as
+        ``jnp.asarray`` would put it. ``seq`` (the executor's batch
+        sequence number) travels with the batch to its sync and
+        fold-back spans."""
         t_start = self._now()
         wall_start = time.monotonic()
         n_total = len(item_keys)
         n = n_total if n_valid is None else int(n_valid)
         valid = np.zeros((n_total,), bool)
         valid[:n] = True
+        sharding = None
         if self.feature_sharding is not None:
             sharding = (self.feature_sharding(features)
                         if callable(self.feature_sharding)
                         else self.feature_sharding)
-            feats_j = jax.device_put(features, sharding)
-        else:
-            feats_j = jax.tree.map(jnp.asarray, features)
+        item_keys = np.asarray(item_keys)
+        keys_j, buckets_j, valid_j, feats_j = jax.device_put(
+            (item_keys.astype(np.uint32, copy=False),
+             np.asarray(buckets, np.int32), valid, features),
+            (None, None, None, sharding))
         return StagedBatch(
-            item_keys=np.asarray(item_keys),
-            keys_j=jnp.asarray(item_keys, jnp.uint32),
-            buckets_j=jnp.asarray(buckets, jnp.int32),
-            valid_j=jnp.asarray(valid),
-            feats_j=feats_j,
+            item_keys=item_keys, keys_j=keys_j, buckets_j=buckets_j,
+            valid_j=valid_j, feats_j=feats_j,
             n=n, n_total=n_total, t_start=t_start,
-            wall_start=wall_start, seq=seq)
+            wall_start=wall_start, seq=seq,
+            stage_s=time.monotonic() - wall_start)
 
     def dispatch_staged(self, staged: StagedBatch) -> PendingShed:
         """Back half: launch the jitted shedding step on staged
@@ -396,6 +409,11 @@ class FusedLoadShedder(LoadShedder):
             self.cache, self.prior, self._eval_params, staged.keys_j,
             staged.buckets_j, staged.valid_j, staged.feats_j, ucap, uthr,
             budget_total, max_evals=max_evals)
+        counts = counts[0] if counts else {}
+        # Start the answers' copies to the host now, so the fold-back
+        # finds them there instead of paying one blocking read each.
+        for out in (trust, tier, n_evald, *counts.values()):
+            out.copy_to_host_async()
         pending = PendingShed(self, trust, tier, n_evald,
                               t_start=staged.t_start,
                               wall_start=staged.wall_start,
@@ -403,8 +421,8 @@ class FusedLoadShedder(LoadShedder):
                               deadline_eff=deadline_eff,
                               skip_observe=not warm,
                               item_keys=staged.item_keys,
-                              seq=staged.seq,
-                              counts=counts[0] if counts else None)
+                              seq=staged.seq, counts=counts,
+                              stage_s=staged.stage_s)
         if self.sim_clock is not None:
             pending.result()
         return pending
@@ -421,11 +439,11 @@ class FusedLoadShedder(LoadShedder):
         t_entry = time.monotonic()
         ready_at_entry = p.is_ready()   # stamps _wall_ready if so
         with obs.span("exec.sync", batch=p.seq, ready=int(ready_at_entry)):
-            trust = np.asarray(p._trust)            # sync point
-            tier = np.asarray(p._tier)
-            n_evald = int(p._n_evald)
-            counts = {k: int(v) for k, v in p._counts.items()}
+            trust, tier, n_evald, counts = jax.device_get(   # sync point
+                (p._trust, p._tier, p._n_evald, p._counts))
         wall_end = time.monotonic()
+        n_evald = int(n_evald)
+        counts = {k: int(v) for k, v in counts.items()}
         if self.sim_clock is not None:
             self.sim_clock.charge_probe()
             self.sim_clock.charge_eval(n_evald)
@@ -465,7 +483,8 @@ class FusedLoadShedder(LoadShedder):
             n_prior=int((tier == TIER_PRIOR).sum()),
             uload=p._n, n_eval_rows=self._slice_cover(n_evald),
             n_expert_rows=counts.get("expert_rows", 0),
-            n_expert_slots=counts.get("expert_slots", 0))
+            n_expert_slots=counts.get("expert_slots", 0),
+            xfer_s=p._stage_s + (wall_end - t_entry))
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None and p._item_keys is not None:

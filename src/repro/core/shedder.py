@@ -215,6 +215,10 @@ class ShedResult:
     # grouped matmuls ran, padding included. 0 for other evaluators.
     n_expert_rows: int = 0
     n_expert_slots: int = 0
+    # Seconds the serving thread spent in the batch's host<->device
+    # transfers: the fused step's upload and its answers' readback. 0
+    # for the host chunk loop and for a request's share of a batch.
+    xfer_s: float = 0.0
 
     @property
     def no_item_dropped(self) -> bool:

@@ -116,6 +116,9 @@ class SchedulerStats:
     # included), summed over slices and layers; 0 without experts.
     n_expert_rows: int = 0
     n_expert_slots: int = 0
+    # Seconds the serving thread spent in batches' host<->device
+    # transfers (ShedResult.xfer_s); 0 on the host chunk loop.
+    xfer_s: float = 0.0
     # Summed queue_delay_s of the responses batches produced, and their
     # number (rescued batches included).
     queue_wait_s: float = 0.0
@@ -136,6 +139,7 @@ class SchedulerStats:
                 "n_cached": self.n_cached,
                 "n_expert_rows": self.n_expert_rows,
                 "n_expert_slots": self.n_expert_slots,
+                "xfer_s": self.xfer_s,
                 "queue_wait_s": self.queue_wait_s,
                 "n_queue_waits": self.n_queue_waits,
                 "mean_batch_fill": (self.n_batched_items
@@ -456,6 +460,7 @@ class Scheduler:
         self.stats.n_cached += shed.n_cached
         self.stats.n_expert_rows += shed.n_expert_rows
         self.stats.n_expert_slots += shed.n_expert_slots
+        self.stats.xfer_s += shed.xfer_s
         if self.quarantine is not None and self.quarantine.any_tracked:
             # Clean completion: decay strikes / close half-open probes
             # for every signature this batch carried.

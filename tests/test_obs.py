@@ -237,3 +237,44 @@ def test_rescued_batch_counts_its_queue_waits():
     assert s["n_queue_waits"] == 3
     assert s["queue_wait_s"] == pytest.approx(
         sum(r.queue_delay_s for r in coord.completed))
+
+
+
+def _record_xfer_steps(coord, steps):
+    """Each batch's ``xfer_s`` beside what it added to its replica's
+    counter."""
+    for rep in coord.replicas:
+        fin = rep.scheduler.executor._finalize
+
+        def finalize(batch, shed, _fin=fin, _stats=rep.scheduler.stats):
+            before = _stats.xfer_s
+            out = _fin(batch, shed)
+            steps.append((shed.xfer_s, _stats.xfer_s - before))
+            return out
+        rep.scheduler.executor._finalize = finalize
+
+
+@pytest.mark.parametrize("drain_mode", ["fused", "host"])
+def test_transfer_seconds_grow_with_every_fused_batch(drain_mode):
+    """``xfer_s``: every fused batch adds its upload and readback time,
+    the host chunk loop adds none; the fleet aggregate sums the
+    replicas' and keeps them across a restart."""
+    coord = _coord(drain_mode, n_replicas=2)
+    steps = []
+    _record_xfer_steps(coord, steps)
+    _serve(coord, [40, 70, 130, 30, 90, 60, 200, 20])
+    s = coord.scheduler_stats()
+    assert len(steps) == s["n_batches"] > 0
+    for own, added in steps:
+        assert added == pytest.approx(own)
+        assert (own > 0) == (drain_mode == "fused")
+    assert s["xfer_s"] == pytest.approx(sum(own for own, _ in steps))
+
+    before = s["xfer_s"]
+    coord.rolling_restart()
+    assert coord.scheduler_stats()["xfer_s"] == pytest.approx(before)
+    _record_xfer_steps(coord, steps)
+    _serve(coord, [50, 80], first=20)
+    s = coord.scheduler_stats()
+    assert s["xfer_s"] == pytest.approx(sum(own for own, _ in steps))
+    assert (s["xfer_s"] > before) == (drain_mode == "fused")
