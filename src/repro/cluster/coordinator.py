@@ -93,7 +93,7 @@ from repro.distribution.fault_tolerance import HedgedDispatch
 from repro.fanout import (FanoutSearcher, ReplicationPolicy,
                           StripeReplicator, mirror_shard_of)
 from repro.scheduling import (Priority, QueuedRequest, Request, Response,
-                              SchedulerConfig)
+                              SchedulerConfig, SchedulerStats)
 from repro.scheduling.priorities import REASON_QUEUE_FULL
 from repro.serving.engine import slo_stats_of
 
@@ -1099,29 +1099,25 @@ class ClusterCoordinator:
             self._restart_hold = False
         return waves
 
-    # Scheduler counters that add up across replicas (and across a
-    # replica's restarts).
-    _SCHED_SUM_KEYS = ("n_submitted", "n_admitted", "n_rejected",
-                       "n_batches", "n_batched_items", "n_hedges",
-                       "n_executor_errors", "n_quarantined",
-                       "n_eval_rows", "n_evaluated", "n_cached",
-                       "n_expert_rows", "n_expert_slots", "xfer_s",
-                       "queue_wait_s", "n_queue_waits")
-
-    @classmethod
-    def _merge_sched_stats(cls, dst: Dict, src: Dict) -> None:
-        for k in cls._SCHED_SUM_KEYS:
-            dst[k] = dst.get(k, 0) + src.get(k, 0)
-        rbr = dst.setdefault("rejected_by_reason", {})
-        for reason, c in src.get("rejected_by_reason", {}).items():
-            rbr[reason] = rbr.get(reason, 0) + c
+    @staticmethod
+    def _merge_sched_stats(dst: Dict, src: Dict) -> None:
+        """Add ``src``'s scheduler counters into ``dst``: every numeric
+        one adds up across replicas and a replica's restarts (the
+        ratio ``mean_batch_fill`` is recomputed from the sums), and
+        ``rejected_by_reason`` merges by reason."""
+        for k, v in src.items():
+            if k == "rejected_by_reason":
+                rbr = dst.setdefault(k, {})
+                for reason, c in v.items():
+                    rbr[reason] = rbr.get(reason, 0) + c
+            elif k != "mean_batch_fill" and isinstance(v, (int, float)):
+                dst[k] = dst.get(k, 0) + v
 
     def _bank_restart_stats(self, rep: ReplicaHandle) -> None:
         """Fold a replica's pre-restart scheduler counters into its
         lifetime base (the rebuilt engine starts from zero, the fleet
         aggregate must not)."""
-        base = self._restart_sched_base.setdefault(
-            rep.replica_id, {"rejected_by_reason": {}})
+        base = self._restart_sched_base.setdefault(rep.replica_id, {})
         self._merge_sched_stats(base, rep.scheduler.stats.as_dict())
 
     # -- Trust-DB gossip -----------------------------------------------------
@@ -1415,8 +1411,8 @@ class ClusterCoordinator:
     def scheduler_stats(self) -> Dict:
         """Fleet aggregate in the single-engine stats shape (drivers and
         reports consume both interchangeably), plus cluster extras."""
-        agg: Dict = {k: 0 for k in self._SCHED_SUM_KEYS}
-        agg["rejected_by_reason"] = {}
+        agg: Dict = {}
+        self._merge_sched_stats(agg, SchedulerStats().as_dict())
         per_replica: Dict[str, Dict] = {}
         live = {rep.replica_id: rep.scheduler.stats.as_dict()
                 for rep in self.replicas}
@@ -1426,7 +1422,7 @@ class ClusterCoordinator:
         # still-live id (the rebuilt engine counts from zero).
         for rid, s in list(self._departed_sched.items()) \
                 + list(live.items()):
-            entry: Dict = {"rejected_by_reason": {}}
+            entry: Dict = {}
             self._merge_sched_stats(entry, s)
             base = self._restart_sched_base.get(rid)
             if base is not None:

@@ -301,12 +301,6 @@ class TrustIRConfig:
     # Trust DB cache
     cache_slots: int = 65536
     cache_ways: int = 4
-    # Cache array layout: True (default) stores keys/values/age as
-    # (n_ways, n_slots) — each way one contiguous slot-indexed row, so
-    # the probe's XLA gather reads one row per way. False is the legacy
-    # (n_slots, n_ways) layout, kept for parity testing and old
-    # snapshots; every cache op infers the layout from the shape.
-    cache_ways_leading: bool = True
     # Average-trust prior
     prior_buckets: int = 1              # 1 = paper-faithful global average
     prior_ewma: float = 0.05

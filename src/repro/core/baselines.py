@@ -41,7 +41,7 @@ class ProcessAll(LoadShedder):
                           response_time_s=rt,
                           deadline_eff_s=self.cfg.deadline_s,
                           n_evaluated=n, n_cached=0, n_prior=0, uload=n,
-                          n_eval_rows=self._chunk_rows(n))
+                          counts={"n_eval_rows": self._chunk_rows(n)})
 
 
 class RLSEDA(LoadShedder):
@@ -72,4 +72,4 @@ class RLSEDA(LoadShedder):
                           deadline_eff_s=self.cfg.overload_deadline_s,
                           n_evaluated=int(len(keep)), n_cached=0,
                           n_prior=0, uload=n,
-                          n_eval_rows=self._chunk_rows(len(keep)))
+                          counts={"n_eval_rows": self._chunk_rows(len(keep))})

@@ -31,10 +31,10 @@ keys, buckets, valid mask and every feature leaf with one
 ``jax.device_put`` of the tuple (the host path converts the pytree then
 re-gathers per chunk), and ``dispatch_staged`` launches the step and at
 once starts the readback of its answers (``copy_to_host_async`` on
-trust, tier, the evaluated count and the evaluator's counts), which
-``.result()`` collects with one ``jax.device_get``. ``process_async``
-composes stage and dispatch into a :class:`PendingShed`. The serving
-thread's seconds in the two transfers are the batch's ``xfer_s``.
+trust, tier and the step's counts), which ``.result()`` collects with
+one ``jax.device_get``. ``process_async`` composes stage and dispatch
+into a :class:`PendingShed`. The serving thread's seconds in the two
+transfers are the batch's ``xfer_s``.
 The ``scheduling.executor.DrainExecutor`` sequences these handles in a
 depth-k in-flight window (``TrustIRConfig.pipeline_depth``): batch N+2
 forms and transfers while batch N computes and N+1 waits, and at depth
@@ -133,16 +133,15 @@ class PendingShed:
     """
 
     def __init__(self, shedder: "FusedLoadShedder", trust, tier,
-                 n_evald, *, t_start: float, wall_start: float,
+                 counts: Dict, *, t_start: float, wall_start: float,
                  n: int, regime, deadline_eff: float,
                  skip_observe: bool = False,
                  item_keys: Optional[np.ndarray] = None, seq: int = -1,
-                 counts: Optional[Dict] = None, stage_s: float = 0.0):
+                 stage_s: float = 0.0):
         self._shedder = shedder
         self._trust = trust
         self._tier = tier
-        self._n_evald = n_evald
-        self._counts = counts or {}
+        self._counts = counts
         self._item_keys = item_keys
         self._t_start = t_start
         self._wall_start = wall_start
@@ -224,13 +223,13 @@ class FusedLoadShedder(LoadShedder):
         self.feature_sharding = feature_sharding
         apply = getattr(evaluate_batch, "apply", None)
         if apply is None:
-            self._apply = lambda _params, feats: evaluate_batch(feats)
+            self._apply = lambda _params, feats: (evaluate_batch(feats), {})
             self._eval_params = None
             self._count_names = ()
         else:
             self._apply = apply
             self._eval_params = evaluate_batch.params
-            self._count_names = tuple(getattr(evaluate_batch, "counts", ()))
+            self._count_names = evaluate_batch.counts
         self._mesh = _mesh_of(self._eval_params)
         if self._mesh is not None:
             # Start the state where the step leaves it (replicated on
@@ -311,21 +310,18 @@ class FusedLoadShedder(LoadShedder):
                 return jax.lax.dynamic_update_slice_in_dim(
                     scores, out.astype(jnp.float32), i * s, 0)
 
-            # An evaluator that names counts (``Evaluator.counts``)
-            # returns them beside its scores, summed here over the
-            # slices; for the others the dict is empty.
-            names = self._count_names
-
+            # The evaluator returns its counts (``Evaluator.counts``)
+            # beside its scores, summed here over the slices; for an
+            # evaluator that names none the dict is empty.
             def one_slice(i, carry):
                 scores, counts = carry
-                out = self._apply(eval_params, slice_features(i))
-                out, c = out if names else (out, {})
+                out, c = self._apply(eval_params, slice_features(i))
                 return (put(scores, out, i),
-                        {k: counts[k] + c[k] for k in names})
+                        {k: counts[k] + c[k] for k in counts})
             scores, counts = jax.lax.fori_loop(
                 0, n_slices, one_slice,
                 (jnp.zeros((rows,), jnp.float32),
-                 {k: jnp.zeros((), jnp.int32) for k in names}))
+                 {k: jnp.zeros((), jnp.int32) for k in self._count_names}))
         with jax.named_scope("scatter_combine"):
             scattered = jnp.zeros((n,), jnp.float32).at[idx].set(
                 jnp.where(eval_valid, scores, 0.0),
@@ -338,11 +334,10 @@ class FusedLoadShedder(LoadShedder):
         with jax.named_scope("prior_update"):
             new_prior = AT.update(prior, buckets, trust, evald,
                                   ewma=self.cfg.prior_ewma)
-        out = (trust, tier, jnp.sum(evald.astype(jnp.int32)), new_cache,
-               new_prior)
-        # The counts come sixth, and only where the evaluator names some:
-        # the others' step keeps its five outputs.
-        return out + (counts,) if names else out
+        # The step's counts: the evaluator's and the evaluated rows;
+        # ``_finish`` reports each as ``"n_" + name``.
+        counts["evaluated"] = jnp.sum(evald.astype(jnp.int32))
+        return trust, tier, counts, new_cache, new_prior
 
     # -- stage / dispatch / finish --------------------------------------------
     def stage(self, item_keys: np.ndarray, buckets: np.ndarray,
@@ -405,24 +400,22 @@ class FusedLoadShedder(LoadShedder):
         warm = self._warmup.warm(
             WarmupGate.signature(n_total, staged.feats_j)
             + (max_evals,))
-        trust, tier, n_evald, self.cache, self.prior, *counts = self._step(
+        trust, tier, counts, self.cache, self.prior = self._step(
             self.cache, self.prior, self._eval_params, staged.keys_j,
             staged.buckets_j, staged.valid_j, staged.feats_j, ucap, uthr,
             budget_total, max_evals=max_evals)
-        counts = counts[0] if counts else {}
         # Start the answers' copies to the host now, so the fold-back
         # finds them there instead of paying one blocking read each.
-        for out in (trust, tier, n_evald, *counts.values()):
+        for out in (trust, tier, *counts.values()):
             out.copy_to_host_async()
-        pending = PendingShed(self, trust, tier, n_evald,
+        pending = PendingShed(self, trust, tier, counts,
                               t_start=staged.t_start,
                               wall_start=staged.wall_start,
                               n=n, regime=regime,
                               deadline_eff=deadline_eff,
                               skip_observe=not warm,
                               item_keys=staged.item_keys,
-                              seq=staged.seq, counts=counts,
-                              stage_s=staged.stage_s)
+                              seq=staged.seq, stage_s=staged.stage_s)
         if self.sim_clock is not None:
             pending.result()
         return pending
@@ -439,11 +432,13 @@ class FusedLoadShedder(LoadShedder):
         t_entry = time.monotonic()
         ready_at_entry = p.is_ready()   # stamps _wall_ready if so
         with obs.span("exec.sync", batch=p.seq, ready=int(ready_at_entry)):
-            trust, tier, n_evald, counts = jax.device_get(   # sync point
-                (p._trust, p._tier, p._n_evald, p._counts))
+            trust, tier, counts = jax.device_get(   # sync point
+                (p._trust, p._tier, p._counts))
         wall_end = time.monotonic()
-        n_evald = int(n_evald)
-        counts = {k: int(v) for k, v in counts.items()}
+        counts = {"n_" + k: int(v) for k, v in counts.items()}
+        n_evald = counts.pop("n_evaluated")
+        counts["n_eval_rows"] = self._slice_cover(n_evald)
+        counts["xfer_s"] = p._stage_s + (wall_end - t_entry)
         if self.sim_clock is not None:
             self.sim_clock.charge_probe()
             self.sim_clock.charge_eval(n_evald)
@@ -481,10 +476,7 @@ class FusedLoadShedder(LoadShedder):
             n_evaluated=n_evald,
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=p._n, n_eval_rows=self._slice_cover(n_evald),
-            n_expert_rows=counts.get("expert_rows", 0),
-            n_expert_slots=counts.get("expert_slots", 0),
-            xfer_s=p._stage_s + (wall_end - t_entry))
+            uload=p._n, counts=counts)
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None and p._item_keys is not None:

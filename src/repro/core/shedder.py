@@ -205,20 +205,13 @@ class ShedResult:
     n_cached: int
     n_prior: int
     uload: int
-    # Rows the evaluator computed for the batch, padding included: the
-    # fused step's slices times the slice size, or the host chunk
-    # loop's chunks times the chunk size. 0 for a request's share of a
-    # batch.
-    n_eval_rows: int = 0
-    # An MoE evaluator's counts over the batch's slices and layers: the
-    # (token, expert) rows its held experts computed, and the rows its
-    # grouped matmuls ran, padding included. 0 for other evaluators.
-    n_expert_rows: int = 0
-    n_expert_slots: int = 0
-    # Seconds the serving thread spent in the batch's host<->device
-    # transfers: the fused step's upload and its answers' readback. 0
-    # for the host chunk loop and for a request's share of a batch.
-    xfer_s: float = 0.0
+    # Additive per-batch counters, by the name ``scheduler_stats()``
+    # reports them under, filled by the shedder that ran the batch:
+    # ``n_eval_rows`` (rows the evaluator computed, padding included),
+    # the fused step's ``xfer_s`` (seconds of host<->device transfer)
+    # and ``"n_" + name`` for each count its evaluator names. Empty for
+    # a request's share of a batch.
+    counts: Dict[str, float] = field(default_factory=dict)
 
     @property
     def no_item_dropped(self) -> bool:
@@ -265,9 +258,7 @@ class LoadShedder:
         self.evaluate_chunk = evaluate_chunk
         self.monitor = monitor or LoadMonitor(cfg)
         self.cache = (cache_state if cache_state is not None
-                      else TC.init(cfg.cache_slots, cfg.cache_ways,
-                                   ways_leading=getattr(
-                                       cfg, "cache_ways_leading", True)))
+                      else TC.init(cfg.cache_slots, cfg.cache_ways))
         self.prior = (prior_state if prior_state is not None
                       else AT.init(cfg.prior_buckets))
         self.sim_clock = sim_clock
@@ -427,7 +418,7 @@ class LoadShedder:
             n_evaluated=int(evald.sum()),
             n_cached=int((tier == TIER_CACHED).sum()),
             n_prior=int((tier == TIER_PRIOR).sum()),
-            uload=n, n_eval_rows=n_eval_rows)
+            uload=n, counts={"n_eval_rows": n_eval_rows})
         if self.adaptive is not None:
             self.adaptive.observe(result)
         if self.on_shed is not None:

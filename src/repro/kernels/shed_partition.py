@@ -193,10 +193,9 @@ def shed_partition(keys: jnp.ndarray, valid: jnp.ndarray,
                    budget_is_total: bool = False,
                    block_rows: int = SUBLANES, interpret: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """keys: (N,) uint32; valid: (N,) bool; cache_*: (ways, slots) in
-    the default ways-leading layout, or legacy (slots, ways) — the
-    layout is inferred from the shape (``trust_cache.dims``) by the
-    XLA probe gather, never by the kernel.
+    """keys: (N,) uint32; valid: (N,) bool; cache_*: (ways, slots),
+    gathered one row per way by the XLA probe
+    (``trust_cache.candidates``) before the kernel runs.
 
     Returns (tier (N,) int32, cached_vals (N,) f32, eval_rank (N,)
     int32). ``eval_rank`` is the arrival-ordered compacted position of

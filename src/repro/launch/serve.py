@@ -111,12 +111,6 @@ it here. Production-scale backbones stay on the fused hot path via:
                                           _backlog_batches /
                                           _latency_frac tune the
                                           no-flap guarantees
-  TrustIRConfig.cache_ways_leading        Trust-DB cache layout:
-                                          True (default) stores
-                                          (ways, slots) so the probe
-                                          gathers one row per way;
-                                          False restores the legacy
-                                          (slots, ways) layout
   TrustIRConfig.fanout_adaptive_quorum    let the coordinator walk
                                           fanout_quorum_k with the
                                           offered regime (tighten

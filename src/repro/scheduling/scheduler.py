@@ -107,18 +107,11 @@ class SchedulerStats:
     n_hedges: int = 0
     n_executor_errors: int = 0      # batches rescued from the prior
     n_quarantined: int = 0          # requests blocked by an open breaker
-    # Per batch, from its ShedResult: rows the evaluator computed
-    # (padding included), candidates evaluated, Trust-DB hits.
-    n_eval_rows: int = 0
+    # Per batch, from its ShedResult: candidates evaluated, Trust-DB
+    # hits, and every counter of its ``counts``, summed by name.
     n_evaluated: int = 0
     n_cached: int = 0
-    # An MoE evaluator's expert rows computed and rows run (padding
-    # included), summed over slices and layers; 0 without experts.
-    n_expert_rows: int = 0
-    n_expert_slots: int = 0
-    # Seconds the serving thread spent in batches' host<->device
-    # transfers (ShedResult.xfer_s); 0 on the host chunk loop.
-    xfer_s: float = 0.0
+    counts: Dict[str, float] = field(default_factory=dict)
     # Summed queue_delay_s of the responses batches produced, and their
     # number (rescued batches included).
     queue_wait_s: float = 0.0
@@ -134,12 +127,9 @@ class SchedulerStats:
                 "n_hedges": self.n_hedges,
                 "n_executor_errors": self.n_executor_errors,
                 "n_quarantined": self.n_quarantined,
-                "n_eval_rows": self.n_eval_rows,
                 "n_evaluated": self.n_evaluated,
                 "n_cached": self.n_cached,
-                "n_expert_rows": self.n_expert_rows,
-                "n_expert_slots": self.n_expert_slots,
-                "xfer_s": self.xfer_s,
+                **self.counts,
                 "queue_wait_s": self.queue_wait_s,
                 "n_queue_waits": self.n_queue_waits,
                 "mean_batch_fill": (self.n_batched_items
@@ -455,12 +445,10 @@ class Scheduler:
         batch_start = end - shed.response_time_s
         self.stats.n_batches += 1
         self.stats.n_batched_items += nv
-        self.stats.n_eval_rows += shed.n_eval_rows
         self.stats.n_evaluated += shed.n_evaluated
         self.stats.n_cached += shed.n_cached
-        self.stats.n_expert_rows += shed.n_expert_rows
-        self.stats.n_expert_slots += shed.n_expert_slots
-        self.stats.xfer_s += shed.xfer_s
+        for k, v in shed.counts.items():
+            self.stats.counts[k] = self.stats.counts.get(k, 0) + v
         if self.quarantine is not None and self.quarantine.any_tracked:
             # Clean completion: decay strikes / close half-open probes
             # for every signature this batch carried.

@@ -33,28 +33,33 @@ from repro.configs.base import GNNConfig, RecsysConfig, TransformerConfig
 
 
 class Evaluator:
-    """A trust evaluator: ``apply(params, features) -> (n,) scores``
-    and the parameters it runs with. Calling it scores one feature
-    batch. The fused drain passes ``params`` into its jitted step as an
-    argument (``core.fused_shedder``): a closure over them would fold
-    every weight into the compiled step as a constant.
+    """A trust evaluator: ``apply(params, features) -> (scores,
+    counts)``, (n,) scores and a dict of int32 device scalars, and the
+    parameters it runs with. Calling it scores one feature batch. The
+    fused drain passes ``params`` into its jitted step as an argument
+    (``core.fused_shedder``): a closure over them would fold every
+    weight into the compiled step as a constant.
 
-    ``counts`` names int32 device scalars (an MoE evaluator's
-    ``expert_rows`` and ``expert_slots``) that ``apply`` then returns
-    beside the scores, as ``(scores, {name: count})``; the fused step
-    sums them over its slices. Calling the evaluator gives the scores
-    alone."""
+    ``counts`` names what the given ``apply`` returns beside its scores
+    (an MoE evaluator's ``expert_rows`` and ``expert_slots``); the fused
+    step sums them over its slices and the scheduler reports each as
+    ``"n_" + name``. An ``apply`` that names none returns the scores
+    alone, and the evaluator pairs them with an empty dict."""
 
     def __init__(self, apply: Callable, params: Any,
                  counts: Tuple[str, ...] = ()):
+        self.counts = tuple(counts)
+        if not self.counts:
+            scores_only = apply
+
+            def apply(params, features):
+                return scores_only(params, features), {}
         self.apply = apply
         self.params = params
-        self.counts = tuple(counts)
         self._jitted = jax.jit(apply)
 
     def __call__(self, features) -> jnp.ndarray:
-        out = self._jitted(self.params, features)
-        return out[0] if self.counts else out
+        return self._jitted(self.params, features)[0]
 
 
 def make_evaluator(arch_id: str, *, smoke: bool = True, seed: int = 0,

@@ -222,7 +222,7 @@ def test_evaluator_runs_only_the_evaluated_slices(case):
     assert res.n_evaluated == int(want_eval.sum()) == n_evald
     rows = S * -(-2 * half // (MAX_SLICES * S))
     assert rows == (2 * S if case == "wide_batch" else S)
-    assert res.n_eval_rows == -(-n_evald // rows) * rows
+    assert res.counts["n_eval_rows"] == -(-n_evald // rows) * rows
     assert traced_rows and set(traced_rows) == {rows}
     if max_evals is not None:       # overflow demoted, never dropped
         assert res.n_prior == n_valid - max_evals
@@ -480,14 +480,15 @@ def _per_array_answers(sh, keys, buckets, feats, n_valid):
         n_total=len(keys), t_start=sh._now(), wall_start=0.0)
     p = sh.dispatch_staged(staged)
     tier = np.asarray(p._tier)
-    counts = {k: int(v) for k, v in p._counts.items()}
+    counts = {"n_" + k: int(v) for k, v in p._counts.items()}
     p.result()
+    n_evaluated = counts.pop("n_evaluated")
+    counts["n_eval_rows"] = sh._slice_cover(n_evaluated)
     return {"trust": np.asarray(p._trust), "tier": tier,
-            "n_evaluated": int(p._n_evald),
+            "n_evaluated": n_evaluated,
             "n_cached": int((tier == TIER_CACHED).sum()),
             "n_prior": int((tier == TIER_PRIOR).sum()),
-            "n_expert_rows": counts.get("expert_rows", 0),
-            "n_expert_slots": counts.get("expert_slots", 0)}
+            "counts": counts}
 
 
 @pytest.mark.parametrize("clock", ["sim", "wall"])
@@ -508,13 +509,16 @@ def test_batched_transfers_answer_as_per_array_transfers(kind, clock):
         want = _per_array_answers(old, keys, buckets, feats, n)
         assert np.array_equal(got.trust, want["trust"])
         assert np.array_equal(got.tier, want["tier"])
-        for k in ("n_evaluated", "n_cached", "n_prior", "n_expert_rows",
-                  "n_expert_slots"):
+        for k in ("n_evaluated", "n_cached", "n_prior"):
             assert getattr(got, k) == want[k], k
-        assert got.xfer_s > 0
+        counts = dict(got.counts)
+        assert counts.pop("xfer_s") > 0
+        assert counts == want["counts"]
     assert got.n_cached > 0 and got.n_evaluated > 0
     if kind == "counting":
-        assert 0 < got.n_expert_rows < got.n_expert_slots
+        assert 0 < counts["n_expert_rows"] < counts["n_expert_slots"]
+    else:
+        assert set(counts) == {"n_eval_rows"}
 
 
 @pytest.mark.parametrize("kind", ["plain", "counting"])
@@ -569,20 +573,18 @@ class _ReadbackRecorder:
 
 @pytest.mark.parametrize("kind", ["plain", "counting"])
 def test_readback_is_requested_at_dispatch(kind):
-    """``dispatch_staged`` starts the copies of trust, tier, the
-    evaluated count and each count to the host before ``result()``
-    is called, so the fold-back finds them there."""
+    """``dispatch_staged`` starts the copies of trust, tier and each of
+    the step's counts (the evaluated count among them) to the host
+    before ``result()`` is called, so the fold-back finds them there."""
     sh = _two_leaf_shedder(kind, "wall")
     step, asked, wrapped = sh._step, [], []
 
     def recording(*args, **kw):
-        trust, tier, n_evald, cache, prior, *counts = step(*args, **kw)
-        outs = [_ReadbackRecorder(a, asked) for a in (trust, tier, n_evald)]
-        if counts:
-            counts = [{k: _ReadbackRecorder(v, asked)
-                       for k, v in counts[0].items()}]
-        wrapped.extend(outs + [v for c in counts for v in c.values()])
-        return (*outs, cache, prior, *counts)
+        trust, tier, counts, cache, prior = step(*args, **kw)
+        trust, tier = (_ReadbackRecorder(a, asked) for a in (trust, tier))
+        counts = {k: _ReadbackRecorder(v, asked) for k, v in counts.items()}
+        wrapped.extend([trust, tier, *counts.values()])
+        return trust, tier, counts, cache, prior
 
     sh._step = recording
     keys, buckets, feats = _two_leaf_batch(96, 128, 9)

@@ -8,6 +8,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import trust_cache as TC
+from repro.core.shedder import TIER_CACHED
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(7)
@@ -175,41 +176,43 @@ def test_shed_partition_vmem_budget_fits_production_config():
     (1000, 0),             # all padding
     (3333, 2048),          # multi-block with ragged tail
 ])
-def test_shed_partition_ways_leading_layout_parity(n, n_valid,
-                                                   cache_mode):
-    """The (ways,)-leading cache retile is bit-exact: the kernel's
-    strided-row probe over a (n_ways, n_slots) cache must agree with
-    the legacy (n_slots, n_ways) gather AND the host oracle on tier,
-    cached value and compacted rank — across ragged tails, all-hit /
-    all-miss caches and the empty batch. Both cache states are built
-    through the same TC.insert calls, so contents are identical."""
+def test_shed_partition_probe_one_row_per_way(n, n_valid, cache_mode):
+    """The probe over the (n_ways, n_slots) Trust DB: the kernel agrees
+    with the host oracle on tier, cached value and compacted rank across
+    ragged tails, all-hit / all-miss caches and the empty batch, and
+    every valid key found in its slot's column of the way rows (a host
+    scan) is answered CACHED with the value stored there."""
     keys = jnp.arange(1, n + 1, dtype=jnp.uint32)
     valid = jnp.arange(n) < n_valid
     ucap, uthr, budget = 256, 128, 300
-    outs = {}
-    for wl in (True, False):
-        cache = TC.init(256, 4, ways_leading=wl)
-        if cache_mode != "all_miss":
-            sel = keys if cache_mode == "all_hit" else keys[::3]
-            cache = TC.insert(cache, sel,
-                              jnp.linspace(0.5, 4.5, sel.shape[0]),
-                              jnp.ones(sel.shape, bool))
-        expect_shape = (4, 256) if wl else (256, 4)
-        assert cache["keys"].shape == expect_shape
-        outs[wl] = ops.shed_partition(
-            keys, valid, cache["keys"], cache["values"],
-            u_capacity=ucap, u_threshold=uthr, budget_dq=budget,
-            budget_is_total=True, interpret=True)
-        tier_r, cval_r, rank_r = ref.shed_partition_ref(
-            keys, valid, cache["keys"], cache["values"], ucap, uthr,
-            budget, budget_is_total=True)
-        tier, cval, rank = outs[wl]
-        assert tier.shape == (n,)
-        assert bool(jnp.all(tier == tier_r))
-        assert bool(jnp.all(rank == rank_r))
-        np.testing.assert_allclose(np.asarray(cval), np.asarray(cval_r))
-    for a, b in zip(outs[True], outs[False]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    cache = TC.init(256, 4)
+    if cache_mode != "all_miss":
+        sel = keys if cache_mode == "all_hit" else keys[::3]
+        cache = TC.insert(cache, sel,
+                          jnp.linspace(0.5, 4.5, sel.shape[0]),
+                          jnp.ones(sel.shape, bool))
+    assert cache["keys"].shape == (4, 256)
+    tier, cval, rank = ops.shed_partition(
+        keys, valid, cache["keys"], cache["values"],
+        u_capacity=ucap, u_threshold=uthr, budget_dq=budget,
+        budget_is_total=True, interpret=True)
+    tier_r, cval_r, rank_r = ref.shed_partition_ref(
+        keys, valid, cache["keys"], cache["values"], ucap, uthr,
+        budget, budget_is_total=True)
+    assert tier.shape == (n,)
+    assert bool(jnp.all(tier == tier_r))
+    assert bool(jnp.all(rank == rank_r))
+    np.testing.assert_allclose(np.asarray(cval), np.asarray(cval_r))
+    slot = np.asarray(TC._hash32(keys)) % 256
+    rows_k = np.asarray(cache["keys"])[:, slot]           # (ways, n)
+    rows_v = np.asarray(cache["values"])[:, slot]
+    match = rows_k == np.asarray(keys)[None, :]
+    hit = match.any(axis=0) & np.asarray(valid)
+    want = np.where(hit, rows_v[match.argmax(axis=0), np.arange(n)], 0.0)
+    np.testing.assert_array_equal(np.asarray(tier)[hit], TIER_CACHED)
+    np.testing.assert_array_equal(np.asarray(cval)[np.asarray(valid)],
+                                  want[np.asarray(valid)])
+    assert hit.any() == (cache_mode != "all_miss" and n_valid > 0)
 
 
 def _probe_cache(keys, mode: str, n_slots=256, n_ways=4):
